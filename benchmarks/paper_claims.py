@@ -898,10 +898,11 @@ ALL_BENCHMARKS = {
 _COLLECTIVE_MATMUL_NUMERICS = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.collective_matmul import ag_matmul, matmul_rs
 
 P_ = 8
-mesh = jax.make_mesh((P_,), ("x",))
+mesh = make_mesh((P_,), ("x",))
 key = jax.random.PRNGKey(0)
 M, K, N = 8 * P_, 16, 12 * P_
 x = jax.random.normal(key, (M, K))

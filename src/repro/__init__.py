@@ -1,22 +1,2 @@
 """repro: reproduction of "Communication Optimization for Distributed
-Training" — models, CCL, network, scheduler, and codesign layers.
-
-Also hosts the jax version-compat shims the whole package relies on.
-"""
-import jax
-
-if not hasattr(jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only (with the
-    # replication check named check_rep rather than check_vma); newer
-    # releases promote it to the top level.  Alias the modern spelling so
-    # one form works everywhere (package code and test scripts).
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _shard_map(f=None, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        if f is None:
-            return lambda g: _experimental_sm(g, **kwargs)
-        return _experimental_sm(f, **kwargs)
-
-    jax.shard_map = _shard_map
+Training" — models, CCL, network, scheduler, and codesign layers."""

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import pallas_call
+
 _TINY = 1e-30
 
 
@@ -48,11 +50,9 @@ def _quantize_kernel(x_ref, *refs, qmax: float, stochastic: bool):
     q_ref[...] = jnp.clip(q, -qmax, qmax).astype(jnp.int8)
 
 
-@functools.partial(jax.jit, static_argnames=("bits", "stochastic", "bm",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("bits", "stochastic", "bm"))
 def quantize_kernel(x, rand_bits=None, *, bits: int = 8,
-                    stochastic: bool = False, bm: int = 8,
-                    interpret: bool = True):
+                    stochastic: bool = False, bm: int = 8):
     """x: (m, n) -> (q int8 (m, n), scale f32 (m, 1)), per-row scales.
     ``rand_bits`` (uint32, same shape) is only required — and only moved
     into VMEM — when ``stochastic=True``; the deterministic hot path stays
@@ -71,7 +71,7 @@ def quantize_kernel(x, rand_bits=None, *, bits: int = 8,
             raise ValueError("stochastic quantize needs rand_bits")
         operands = (x, rand_bits)
         in_specs = [block, block]
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(m // bm,),
         in_specs=in_specs,
@@ -79,7 +79,6 @@ def quantize_kernel(x, rand_bits=None, *, bits: int = 8,
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((m, n), jnp.int8),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)],
-        interpret=interpret,
     )(*operands)
 
 
@@ -87,20 +86,19 @@ def _dequantize_kernel(q_ref, scale_ref, out_ref):
     out_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def dequantize_kernel(q, scale, *, bm: int = 8, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bm",))
+def dequantize_kernel(q, scale, *, bm: int = 8):
     """(q int8 (m, n), scale (m, 1)) -> f32 (m, n)."""
     m, n = q.shape
     bm = min(bm, m)
     assert m % bm == 0, (m, bm)
-    return pl.pallas_call(
+    return pallas_call(
         _dequantize_kernel,
         grid=(m // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0)),
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
     )(q, scale)
 
 
@@ -109,20 +107,19 @@ def _sparsify_kernel(x_ref, thresh_ref, out_ref):
     out_ref[...] = jnp.where(jnp.abs(x) >= thresh_ref[...], x, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def sparsify_kernel(x, thresh, *, bm: int = 8, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bm",))
+def sparsify_kernel(x, thresh, *, bm: int = 8):
     """x: (m, n), thresh: (m, 1) -> masked f32 (m, n)."""
     m, n = x.shape
     bm = min(bm, m)
     assert m % bm == 0, (m, bm)
-    return pl.pallas_call(
+    return pallas_call(
         _sparsify_kernel,
         grid=(m // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0)),
                   pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
     )(x, thresh)
 
 
@@ -132,9 +129,8 @@ def _matmul_kernel(a_ref, b_ref, out_ref):
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
-def matmul_kernel(a, b, *, bm: int = 128, bn: int = 128,
-                  interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bm", "bn"))
+def matmul_kernel(a, b, *, bm: int = 128, bn: int = 128):
     """Blocked (m, k) x (k, n) -> f32 (m, n); k rides whole (PowerSGD
     ranks are tiny, the k dimension is the payload one)."""
     m, k = a.shape
@@ -142,12 +138,11 @@ def matmul_kernel(a, b, *, bm: int = 128, bn: int = 128,
     assert k == k2, (a.shape, b.shape)
     bm, bn = min(bm, m), min(bn, n)
     assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
-    return pl.pallas_call(
+    return pallas_call(
         _matmul_kernel,
         grid=(m // bm, n // bn),
         in_specs=[pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
                   pl.BlockSpec((k, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
     )(a, b)

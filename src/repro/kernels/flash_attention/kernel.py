@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -82,11 +84,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window=None,
-                           bq: int = 128, bk: int = 128,
-                           interpret: bool = True):
+                           bq: int = 128, bk: int = 128):
     """q: (B, H, Sq, D); k, v: (B, KV, Sk, D) with H % KV == 0."""
     b, h, sq, d = q.shape
     _, kv, sk, _ = k.shape
@@ -102,7 +102,7 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window=None,
         _attn_kernel, scale=scale, causal=causal, window=window, bq=bq,
         bk=bk, num_kblocks=nk)
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
@@ -120,5 +120,4 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((bq,), jnp.float32),      # running denom
             pltpu.VMEM((bq, d), jnp.float32),    # output acc
         ],
-        interpret=interpret,
     )(q, k, v)

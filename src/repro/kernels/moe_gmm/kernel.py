@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     ik = pl.program_id(3)
@@ -36,9 +38,8 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bc", "bf", "bd", "interpret"))
-def moe_gmm_kernel(x, w, *, bc: int = 128, bf: int = 128, bd: int = 256,
-                   interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("bc", "bf", "bd"))
+def moe_gmm_kernel(x, w, *, bc: int = 128, bf: int = 128, bd: int = 256):
     """x: (E, C, d); w: (E, d, f) -> (E, C, f)."""
     e, c, d = x.shape
     _, _, f = w.shape
@@ -49,7 +50,7 @@ def moe_gmm_kernel(x, w, *, bc: int = 128, bf: int = 128, bd: int = 256,
     nk = d // bd
 
     kernel = functools.partial(_gmm_kernel, nk=nk)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(e, c // bc, f // bf, nk),
         in_specs=[
@@ -60,5 +61,4 @@ def moe_gmm_kernel(x, w, *, bc: int = 128, bf: int = 128, bd: int = 256,
                                lambda ie, ic, if_, ik: (ie, ic, if_)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        interpret=interpret,
     )(x, w)

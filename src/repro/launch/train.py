@@ -40,6 +40,8 @@ from repro.configs import ARCHS, get_config, smoke_config  # noqa: E402
 from repro.core.types import MeshConfig, TrainConfig  # noqa: E402
 from repro.data.pipeline import make_batches  # noqa: E402
 from repro.data.stubs import audio_frames, vision_patches  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models.transformer import init_params  # noqa: E402
 from repro.optim.adamw import init_opt_state  # noqa: E402
 from repro.parallel.planner import make_ctx, param_specs  # noqa: E402
@@ -60,6 +62,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     tcfg = TrainConfig(learning_rate=args.lr, warmup_steps=10,
@@ -69,7 +72,7 @@ def main() -> None:
     if args.devices > 1:
         d = args.model_axis
         mcfg = MeshConfig(shape=(args.devices // d, d))
-        mesh = jax.make_mesh(mcfg.shape, mcfg.axis_names)
+        mesh = make_mesh(mcfg.shape, mcfg.axis_names)
         ctx = make_ctx(mesh, mcfg, remat=False)
         print(f"mesh: {dict(zip(mcfg.axis_names, mcfg.shape))}")
 

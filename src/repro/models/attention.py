@@ -224,10 +224,16 @@ def multihead_attention(q, k, v, *, q_pos, k_pos, causal, window=None,
                         causal_skip=False, unroll=False,
                         use_pallas=False):
     """Dispatch between plain / flash-jnp / Pallas paths.
-    q: (B,Sq,H,hd) ungrouped."""
-    if use_pallas and q.shape[1] == k.shape[1] and \
-            q.shape[-1] == v.shape[-1] and q.shape[1] % 128 == 0:
-        # Pallas kernel path (TPU production; interpret=True on CPU).
+    q: (B,Sq,H,hd) ungrouped.  ``use_pallas`` runs the flash-attention
+    kernel (compiled on TPU, interpreted on CPU) and raises for shapes it
+    does not cover rather than falling back."""
+    if use_pallas:
+        if not (q.shape[1] == k.shape[1] and q.shape[-1] == v.shape[-1]
+                and q.shape[1] % 128 == 0):
+            raise ValueError(
+                f"Pallas flash attention needs self-attention with Sq == Sk "
+                f"a multiple of 128 and equal q/v head dims; got q "
+                f"{q.shape}, k {k.shape}, v {v.shape}")
         # Layout: (B,S,H,D) -> (B,H,S,D); contiguous positions assumed.
         from repro.kernels.flash_attention.ops import flash_attention
         out = flash_attention(

@@ -71,9 +71,9 @@ class CollectiveProbe:
 
 def _default_mesh():
     import jax
-    import numpy as np
-    devices = jax.devices()
-    return jax.sharding.Mesh(np.array(devices), ("x",))
+
+    from repro.launch.mesh import make_mesh
+    return make_mesh((len(jax.devices()),), ("x",))
 
 
 def probe_all_reduce(impl: str, size_bytes: int, mesh=None,
@@ -126,7 +126,7 @@ def probe_all_reduce(impl: str, size_bytes: int, mesh=None,
         modeled_s=algo_cost("all_reduce", algorithm, size_bytes, p, cp),
         runs_s=runs,
         model_terms=cost_terms("all_reduce", algorithm, size_bytes, p, cp),
-        device_kind=jax.devices()[0].platform)
+        device_kind=jax.devices()[0].device_kind)
 
 
 def probe_suite(impls: Sequence[str] = ("ring", "bidir_ring"),

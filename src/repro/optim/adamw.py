@@ -13,7 +13,9 @@ from repro.core.types import TrainConfig
 
 
 def init_opt_state(params: Any) -> Dict[str, Any]:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    # zeros_like keeps each parameter's sharding (no state lands whole on
+    # one device of a mesh)
+    zeros = lambda p: jnp.zeros_like(p, jnp.float32)
     return {
         "m": jax.tree.map(zeros, params),
         "v": jax.tree.map(zeros, params),

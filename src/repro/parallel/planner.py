@@ -51,8 +51,8 @@ class ParallelCtx:
     # analysis (which visits while bodies once) counts every layer
     ep_weight_stationary: bool = False  # decode MoE: keep FSDP'd expert
     # weights sharded; psum tiny activations instead of gathering weights
-    use_pallas: bool = False  # attention via the Pallas kernel (TPU prod
-    # path; interpret-executes on CPU — used by integration tests)
+    use_pallas: bool = False  # attention via the Pallas kernel (compiled
+    # on TPU, interpreted on CPU; unsupported shapes raise)
     act_spec: Optional[P] = None
     logit_spec: Optional[P] = None
     notes: List[str] = field(default_factory=list)

@@ -13,8 +13,11 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 def run_multidevice(script: str, num_devices: int = 8,
                     timeout: int = 420) -> str:
     """Run ``script`` in a subprocess with N fake host devices.  The script
-    should print 'OK' on success; raises on failure."""
+    should print 'OK' on success; raises on failure.  The child is pinned
+    to the CPU backend: it is a virtual-device check, and on a host with a
+    TPU the chip may already belong to another process."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
                         f"{num_devices}")
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")

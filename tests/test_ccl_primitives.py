@@ -11,12 +11,13 @@ SCRIPT = """
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.ccl.primitives import (ring_all_reduce, bidir_ring_all_reduce,
                                   compressed_ring_all_reduce,
                                   latency_bound_all_reduce, ring_all_gather,
                                   ring_reduce_scatter)
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = jnp.arange(8 * 48, dtype=jnp.float32).reshape(8, 48) / 7.0
 
 def psum_ref(x, spec):
@@ -123,8 +124,9 @@ def test_compressed_ring_inline_multidevice():
     from jax.sharding import PartitionSpec as P
 
     from repro.ccl.primitives import compressed_ring_all_reduce
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = make_mesh((8,), ("x",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
     got = jax.jit(jax.shard_map(
         lambda xl: compressed_ring_all_reduce(xl[0], "x", 8)[None],

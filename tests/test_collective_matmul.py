@@ -7,9 +7,10 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collective_matmul import ag_matmul, matmul_rs
 from repro.ccl.primitives import torus2d_all_reduce
+from repro.launch.mesh import make_mesh
 
 P_ = 4
-mesh = jax.make_mesh((P_,), ("x",))
+mesh = make_mesh((P_,), ("x",))
 key = jax.random.PRNGKey(0)
 M, K, N = 8 * P_, 16, 12 * P_
 x = jax.random.normal(key, (M, K))
@@ -37,7 +38,7 @@ np.testing.assert_allclose(np.asarray(y2), np.asarray(x2 @ w2), atol=1e-4)
 print("matmul_rs ok")
 
 # --- 2D-torus dimension-ordered all-reduce on a (2,2) mesh ---
-mesh2 = jax.make_mesh((2, 2), ("r", "c"))
+mesh2 = make_mesh((2, 2), ("r", "c"))
 z = jnp.arange(4 * 10, dtype=jnp.float32).reshape(4, 10)
 def body_t(zl):
     return torus2d_all_reduce(zl[0], "r", "c", 2, 2)[None]

@@ -11,6 +11,7 @@ from hypothesis import strategies as st
 
 from helpers import run_multidevice
 from repro.configs import smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 
 
@@ -51,11 +52,12 @@ EP_SCRIPT = """
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import moe as moe_mod
 from repro.parallel.planner import ParallelCtx
 
 cfg = dataclasses.replace(smoke_config("dbrx-132b"), num_shared_experts=0)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 ctx = ParallelCtx(mesh=mesh, data_axes=("data",), model_axis="model",
                   capacity_factor=float(cfg.num_experts))  # no drops
 key = jax.random.PRNGKey(0)
@@ -99,7 +101,7 @@ def test_capacity_drops_are_bounded():
     key = jax.random.PRNGKey(1)
     p = moe_mod.init_moe(key, cfg, jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 2), (2, 16, cfg.d_model))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     y, _ = moe_mod.moe_ep_train(p, cfg, x, mesh, "model", ("data",),
                                 capacity_factor=0.25)
     assert bool(jnp.isfinite(y).all())

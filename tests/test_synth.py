@@ -374,9 +374,10 @@ from jax.sharding import PartitionSpec as P
 from repro.ccl.primitives import make_synthesized, synthesized_collective
 from repro.ccl.synth import atp_schedule, synthesize_schedule
 from repro.core.demand import CommTask
+from repro.launch.mesh import make_mesh
 from repro.net.topology import fat_tree, full_mesh, ring
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 # integer-valued floats: float32 sums are exact, so lossless synthesized
 # all-reduce must BIT-match psum (not just be close)
 x = jnp.arange(8 * 48, dtype=jnp.float32).reshape(8, 48) - 150.0

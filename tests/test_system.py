@@ -91,10 +91,11 @@ def test_serving_greedy_matches_forward_argmax():
 
 PIPELINE_SCRIPT = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import make_pipeline_fn, bubble_fraction
 
 P_STAGES, M, MB, D = 4, 8, 2, 16
-mesh = jax.make_mesh((P_STAGES,), ("pipe",))
+mesh = make_mesh((P_STAGES,), ("pipe",))
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (P_STAGES, D, D)) * 0.2
 
@@ -138,10 +139,11 @@ def test_pipeline_parallelism_multidevice():
 INTERLEAVED_SCRIPT = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
+from repro.launch.mesh import make_mesh
 from repro.parallel.pipeline import interleaved_pipeline_apply
 
 P_, V, M, MB, D = 4, 2, 6, 2, 8
-mesh = jax.make_mesh((P_,), ("pipe",))
+mesh = make_mesh((P_,), ("pipe",))
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (P_, V, D, D)) * 0.3
 x = jax.random.normal(jax.random.fold_in(key, 1), (M, MB, D))
@@ -172,7 +174,7 @@ def loss_ref(w_):
     for k in range(V * P_):
         r = jnp.tanh(r @ w_[k % P_, k // P_])
     return jnp.sum(r ** 2)
-np.testing.assert_allclose(np.asarray(jax.grad(loss)(w)),
+np.testing.assert_allclose(np.asarray(jax.jit(jax.grad(loss))(w)),
                            np.asarray(jax.grad(loss_ref)(w)), atol=1e-4)
 print("interleaved grad ok")
 print("OK")
