@@ -1,0 +1,18 @@
+"""Model: device time a step of the ``ffn`` blocks.
+
+Ops whose HLO metadata names the ``ffn`` scope (``models/transformer.py``
+``_apply_layer``: ``norm2``, the FFN, its residual add; forward, backward and
+recomputed), by ``scopes.py``'s rule, mean over cores, in ms.  Collectives the
+compiler put in the block (Megatron all-reduces) count here too, as in
+``collective_ms``.
+"""
+import scopes
+
+LAYER = "model"
+UNIT = "ms"
+MOVES = "train_tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(r):
+    return scopes.ms(r, scopes.block, "ffn")

@@ -6,6 +6,13 @@ configs lower and compile quickly in the multi-pod dry-run.  Heterogeneous
 patterns (Jamba's 1 attn : 7 mamba, Llama-Vision's 4 self : 1 cross) become
 a short unrolled period inside the scan body.
 
+Each block runs under a ``jax.named_scope`` named by its kind: ``attention``,
+``ssm`` or ``cross_attention`` (norm, mixer, residual), ``ffn`` or ``moe``
+(norm, FFN, residual), and ``vocab`` (the embedding gather, the final norm and
+the logits; the train step's loss too).  The names go into the HLO metadata
+only, so the compiled program is the same instruction for instruction; the
+benchmark charges each device op to its block by them.
+
 Every function takes an optional ``ctx`` (repro.parallel.planner.ParallelCtx)
 that carries the mesh + axis names for the expert-parallel shard_map path and
 activation sharding constraints; with ``ctx=None`` everything runs on a
@@ -100,43 +107,55 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.float32) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _mixer_scope(spec: LayerSpec) -> str:
+    return {"attn": "attention", "cross_attn": "cross_attention"}.get(
+        spec.mixer, "ssm")
+
+
+def _ffn_scope(spec: LayerSpec) -> str:
+    return "moe" if spec.ffn == "moe" else "ffn"
+
+
 def _apply_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, x, positions,
                  context, ctx, window, cross_lp=None):
-    h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
     unroll = _flag(ctx, "unroll_layers")  # dry-run cost mode: see attention
-    if spec.mixer == "attn":
-        if cfg.attention == "mla":
-            h = attn.mla_forward(lp["mixer"], cfg, h, positions,
-                                 window=window, unroll=unroll,
-                                 causal_skip=_flag(ctx, "causal_skip"))
+    with jax.named_scope(_mixer_scope(spec)):
+        h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
+        if spec.mixer == "attn":
+            if cfg.attention == "mla":
+                h = attn.mla_forward(lp["mixer"], cfg, h, positions,
+                                     window=window, unroll=unroll,
+                                     causal_skip=_flag(ctx, "causal_skip"))
+            else:
+                h = attn.gqa_forward(lp["mixer"], cfg, h, positions,
+                                     window=window, unroll=unroll,
+                                     causal_skip=_flag(ctx, "causal_skip"),
+                                     use_pallas=_flag(ctx, "use_pallas"))
+        elif spec.mixer == "cross_attn":
+            h = attn.cross_attention_forward(lp["mixer"], cfg, h, context,
+                                             unroll=unroll)
         else:
-            h = attn.gqa_forward(lp["mixer"], cfg, h, positions,
-                                 window=window, unroll=unroll,
-                                 causal_skip=_flag(ctx, "causal_skip"),
-                                 use_pallas=_flag(ctx, "use_pallas"))
-    elif spec.mixer == "cross_attn":
-        h = attn.cross_attention_forward(lp["mixer"], cfg, h, context,
-                                         unroll=unroll)
-    else:
-        h = ssm.mamba_forward(lp["mixer"], cfg, h)
-    x = x + h
-    x = _constrain(x, ctx, "act_spec")
+            h = ssm.mamba_forward(lp["mixer"], cfg, h)
+        x = x + h
+        x = _constrain(x, ctx, "act_spec")
     aux = jnp.zeros((), jnp.float32)
 
     # encoder-decoder: interleave a cross-attention block after self-attn
     if cross_lp is not None:
-        h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
-        x = x + attn.cross_attention_forward(cross_lp, cfg, h, context)
-        x = _constrain(x, ctx, "act_spec")
+        with jax.named_scope("cross_attention"):
+            h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
+            x = x + attn.cross_attention_forward(cross_lp, cfg, h, context)
+            x = _constrain(x, ctx, "act_spec")
 
     if spec.ffn != "none":
-        h2 = rms_norm(x, lp["norm2"]["scale"], cfg.norm_eps)
-        if spec.ffn == "moe":
-            y, aux = moe_mod.moe_apply(lp["ffn"], cfg, h2, ctx=ctx)
-        else:
-            y = ffn_apply(lp["ffn"], h2, cfg.ffn_act)
-        x = x + y
-        x = _constrain(x, ctx, "act_spec")
+        with jax.named_scope(_ffn_scope(spec)):
+            h2 = rms_norm(x, lp["norm2"]["scale"], cfg.norm_eps)
+            if spec.ffn == "moe":
+                y, aux = moe_mod.moe_apply(lp["ffn"], cfg, h2, ctx=ctx)
+            else:
+                y = ffn_apply(lp["ffn"], h2, cfg.ffn_act)
+            x = x + y
+            x = _constrain(x, ctx, "act_spec")
     return x, aux
 
 
@@ -194,6 +213,16 @@ def _vocab_bias(cfg: ModelConfig, dtype):
     return jnp.where(v < cfg.vocab_size, 0.0, attn.NEG_INF).astype(dtype)
 
 
+def _head(cfg: ModelConfig, params: dict, x):
+    """Final norm, logits and the padded vocabulary's mask."""
+    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    else:
+        logits = x @ params["lm_head"]
+    return logits + _vocab_bias(cfg, logits.dtype)
+
+
 def encode(cfg: ModelConfig, params: dict, frames: jax.Array, ctx=None
            ) -> jax.Array:
     """Encoder stack over stub frame embeddings (B, T, d) -> context."""
@@ -204,12 +233,14 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array, ctx=None
 
     def body(carry, lp):
         h = carry
-        hh = rms_norm(h, lp["pos0"]["norm1"]["scale"], cfg.norm_eps)
-        hh = attn.gqa_forward(lp["pos0"]["mixer"], cfg, hh, positions)
-        h = h + hh
-        h2 = rms_norm(h, lp["pos0"]["norm2"]["scale"], cfg.norm_eps)
-        h = h + ffn_apply(lp["pos0"]["ffn"], h2, cfg.ffn_act)
-        h = _constrain(h, ctx, "act_spec")
+        with jax.named_scope("attention"):
+            hh = rms_norm(h, lp["pos0"]["norm1"]["scale"], cfg.norm_eps)
+            hh = attn.gqa_forward(lp["pos0"]["mixer"], cfg, hh, positions)
+            h = h + hh
+        with jax.named_scope("ffn"):
+            h2 = rms_norm(h, lp["pos0"]["norm2"]["scale"], cfg.norm_eps)
+            h = h + ffn_apply(lp["pos0"]["ffn"], h2, cfg.ffn_act)
+            h = _constrain(h, ctx, "act_spec")
         return h, None
 
     if _flag(ctx, "remat"):
@@ -229,8 +260,9 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
     ``context``: encoder output (audio), vision patch embeddings (vlm), or
     None.  ``window``: overrides cfg.sliding_window (long-context variant).
     """
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = _constrain(x, ctx, "act_spec")
+    with jax.named_scope("vocab"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x = _constrain(x, ctx, "act_spec")
     positions = jnp.arange(tokens.shape[1])
     if cfg.is_encoder_decoder and context is None:
         raise ValueError("encoder-decoder model requires context")
@@ -238,13 +270,9 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
     cross_stack = params.get("cross")
     x, aux = _run_groups(params, cfg, x, positions, context, ctx, win,
                          cross_stack=cross_stack)
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = x @ params["lm_head"]
-    logits = logits + _vocab_bias(cfg, logits.dtype)
-    logits = _constrain(logits, ctx, "logit_spec")
+    with jax.named_scope("vocab"):
+        logits = _head(cfg, params, x)
+        logits = _constrain(logits, ctx, "logit_spec")
     return logits, aux
 
 
@@ -288,29 +316,34 @@ def init_cache(cfg: ModelConfig, params: dict, batch: int, max_len: int,
 
 def _decode_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, x, lcache,
                   pos, ctx, window, cross_lp=None, cross_cache=None):
-    h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
     new_cache = lcache
-    if spec.mixer == "attn":
-        if cfg.attention == "mla":
-            h, new_cache = attn.mla_decode(lp["mixer"], cfg, h, lcache, pos)
-        else:
-            h, new_cache = attn.gqa_decode(lp["mixer"], cfg, h, lcache, pos,
-                                           window=window)
-    elif spec.mixer == "cross_attn":
-        h = attn.cross_attention_decode(lp["mixer"], cfg, h, lcache)
-    else:
-        h, new_cache = ssm.mamba_decode(lp["mixer"], cfg, h, lcache)
-    x = x + h
-    if cross_lp is not None:
+    with jax.named_scope(_mixer_scope(spec)):
         h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
-        x = x + attn.cross_attention_decode(cross_lp, cfg, h, cross_cache)
-    if spec.ffn != "none":
-        h2 = rms_norm(x, lp["norm2"]["scale"], cfg.norm_eps)
-        if spec.ffn == "moe":
-            y, _ = moe_mod.moe_apply(lp["ffn"], cfg, h2, ctx=ctx, decode=True)
+        if spec.mixer == "attn":
+            if cfg.attention == "mla":
+                h, new_cache = attn.mla_decode(lp["mixer"], cfg, h, lcache,
+                                               pos)
+            else:
+                h, new_cache = attn.gqa_decode(lp["mixer"], cfg, h, lcache,
+                                               pos, window=window)
+        elif spec.mixer == "cross_attn":
+            h = attn.cross_attention_decode(lp["mixer"], cfg, h, lcache)
         else:
-            y = ffn_apply(lp["ffn"], h2, cfg.ffn_act)
-        x = x + y
+            h, new_cache = ssm.mamba_decode(lp["mixer"], cfg, h, lcache)
+        x = x + h
+    if cross_lp is not None:
+        with jax.named_scope("cross_attention"):
+            h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
+            x = x + attn.cross_attention_decode(cross_lp, cfg, h, cross_cache)
+    if spec.ffn != "none":
+        with jax.named_scope(_ffn_scope(spec)):
+            h2 = rms_norm(x, lp["norm2"]["scale"], cfg.norm_eps)
+            if spec.ffn == "moe":
+                y, _ = moe_mod.moe_apply(lp["ffn"], cfg, h2, ctx=ctx,
+                                         decode=True)
+            else:
+                y = ffn_apply(lp["ffn"], h2, cfg.ffn_act)
+            x = x + y
     x = _constrain(x, ctx, "act_spec")
     return x, new_cache
 
@@ -321,8 +354,9 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
     """tokens: (B, 1) int32; pos: scalar int32 (position of the new token).
     Returns (logits (B,1,V_pad), new_cache)."""
     win = window if window is not None else cfg.sliding_window
-    x = jnp.take(params["embed"], tokens, axis=0)
-    x = _constrain(x, ctx, "act_spec")
+    with jax.named_scope("vocab"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        x = _constrain(x, ctx, "act_spec")
     new_cache = {}
     layer_offset = 0
     for gi, (period, repeats) in enumerate(cfg.layer_groups()):
@@ -362,10 +396,6 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
         layer_offset += repeats * len(period)
     if cfg.is_encoder_decoder:
         new_cache["cross"] = cache["cross"]
-    x = rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = x @ params["lm_head"]
-    logits = logits + _vocab_bias(cfg, logits.dtype)
+    with jax.named_scope("vocab"):
+        logits = _head(cfg, params, x)
     return logits, new_cache

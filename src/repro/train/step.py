@@ -4,7 +4,11 @@ The returned function is pure and pjit-able; the launcher supplies
 in/out shardings from the planner.  Gradient synchronization across the
 data axes falls out of the sharding propagation: with plain DP specs XLA
 emits All-Reduce, with ZeRO-1 specs Reduce-Scatter + All-Gather — the
-Para.-layer knob (TrainConfig.grad_sync) the survey describes."""
+Para.-layer knob (TrainConfig.grad_sync) the survey describes.
+
+The loss runs under the model's ``vocab`` scope and the update (gradient cast,
+schedule, clip, AdamW) under ``optimizer``: ``jax.named_scope`` names that
+reach the HLO metadata only (see ``models.transformer``)."""
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, Optional
@@ -29,7 +33,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             context = encode(cfg, p, context, ctx=ctx)
         logits, aux = forward(cfg, p, batch["tokens"], context=context,
                               ctx=ctx)
-        ce = cross_entropy(logits, batch["labels"])
+        with jax.named_scope("vocab"):
+            ce = cross_entropy(logits, batch["labels"])
         loss = ce + cfg.router_aux_loss * aux
         return loss, {"ce": ce, "aux": aux}
 
@@ -72,13 +77,14 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             loss = loss / nmb
             metrics = jax.tree.map(lambda m: m / nmb, metrics)
 
-        if tcfg.grad_dtype == "bf16":
-            # sync-precision cast: halves the DP gradient collective bytes;
-            # AdamW re-accumulates in f32
-            grads = jax.tree.map(lambda g: g.astype(jnp.bfloat16), grads)
-        lr = lr_schedule(opt_state["step"], tcfg)
-        new_params, new_state, opt_metrics = adamw_update(
-            params, grads, opt_state, tcfg, lr)
+        with jax.named_scope("optimizer"):
+            if tcfg.grad_dtype == "bf16":
+                # sync-precision cast: halves the DP gradient collective
+                # bytes; AdamW re-accumulates in f32
+                grads = jax.tree.map(lambda g: g.astype(jnp.bfloat16), grads)
+            lr = lr_schedule(opt_state["step"], tcfg)
+            new_params, new_state, opt_metrics = adamw_update(
+                params, grads, opt_state, tcfg, lr)
         metrics = dict(metrics, loss=loss, lr=lr, **opt_metrics)
         return new_params, new_state, metrics
 

@@ -7,6 +7,7 @@ The topology is described inside a module fixture, never at import, and
 the tests skip where it cannot be described (no TPU compiler installed).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,11 +113,17 @@ def test_qwen2_train_step_fits_one_v5e(one_chip):
     opt = place(jax.eval_shape(init_opt_state, params))
     batch = {k: _shape((8, 128), jnp.int32, one_chip)
              for k in ("tokens", "labels")}
-    m = _compile(make_train_step(cfg, tcfg), params, opt, batch,
-                 donate_argnums=(0, 1)).memory_analysis()
+    compiled = _compile(make_train_step(cfg, tcfg), params, opt, batch,
+                        donate_argnums=(0, 1))
+    m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert total <= V5E_HBM_BYTES, total / 2 ** 30
+    # the blocks' named scopes reach the fusions the chip runs
+    fusions = [l for l in compiled.as_text().splitlines() if " fusion(" in l]
+    for scope in ("attention", "ffn", "vocab", "optimizer"):
+        assert any(re.search(rf'op_name="[^"]*[/(]{scope}[/)]', l)
+                   for l in fusions), scope
 
 
 def test_qwen2_train_step_compiles_on_2x2_mesh(topo):
