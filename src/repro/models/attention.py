@@ -1,17 +1,22 @@
 """Attention flavours: GQA (+RoPE, QKV-bias, sliding-window), MLA, cross-attn.
 
-Two compute paths:
-  * plain einsum attention for short sequences (smoke tests, examples);
+Three compute paths:
+  * plain einsum attention where Sq * Sk <= 2048^2 (short training
+    sequences, smoke tests, examples);
   * flash-style chunked attention in pure jnp (two nested ``lax.scan``) for
-    long sequences — O(S * chunk) live memory, small HLO, used by the dry-run.
-    The Pallas kernel in ``repro.kernels.flash_attention`` implements the same
-    contract for the TPU production path.
+    longer sequences, with its own FlashAttention-2 backward — O(S * chunk)
+    live memory in both directions, small HLO.  It is the train path's
+    long-sequence attention and the dry-run's;
+  * the Pallas kernel in ``repro.kernels.flash_attention`` (``use_pallas``),
+    forward only: it has no backward, so training does not take it.
 
 Decode attends one new token against a KV cache; sliding-window caches are
 ring buffers of ``window`` slots.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -104,64 +109,84 @@ def _plain_attention(q, k, v, *, q_pos, k_pos, causal, window, logit_dtype):
     return out
 
 
-def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
-                         q_chunk=_Q_CHUNK, kv_chunk=_KV_CHUNK,
-                         causal_skip: bool = False, unroll: bool = False):
-    """Flash-style online-softmax attention, pure jnp.
+@dataclasses.dataclass(frozen=True)
+class _Chunking:
+    """Static shape of a chunked attention call: its mask and its loops."""
+    causal: bool
+    window: Optional[int]
+    q_chunk: int
+    kv_chunk: int
+    causal_skip: bool   # per-q-chunk KV extents (causal only)
+    unroll: bool        # python loops for both chunk levels
 
-    q: (B,Sq,KV,G,hd); k,v: (B,Sk,KV,hd); q_pos: (Sq,), k_pos: (Sk,).
-    ``causal_skip``: unroll the q-chunk loop in python and slice the KV range
-    each q chunk can actually see (exact-causal FLOPs; bigger HLO).  Default
-    is a uniform double-scan (2x the causal FLOPs, tiny HLO) — this is the
-    baseline/optimized pair used in EXPERIMENTS.md §Perf.
+    def kv_extent(self, i: int, sk: int) -> Tuple[int, int]:
+        """The keys ``[lo, hi)`` that q chunk ``i`` visits."""
+        if not self.causal_skip:
+            return 0, sk
+        lo = 0
+        if self.window is not None:
+            lo = max(0, (i * self.q_chunk - int(self.window))
+                     // self.kv_chunk * self.kv_chunk)
+        hi = ((i + 1) * self.q_chunk + self.kv_chunk - 1) // self.kv_chunk
+        return lo, min(hi * self.kv_chunk, sk)
 
-    ``unroll``: python loops for BOTH chunk levels (dry-run cost mode only —
-    XLA cost analysis visits scan bodies once, so the scanned form
-    undercounts attention FLOPs/bytes by ~nq*nk).
-    """
-    if unroll:
-        q_chunk = kv_chunk = 2048  # fewer, MXU-aligned bodies for compile
-    b, sq, nkv, g, hd = q.shape
-    sk = k.shape[1]
-    vd = v.shape[-1]  # may differ from hd (MLA: qk 192, v 128)
-    q_chunk = min(q_chunk, sq)
-    kv_chunk = min(kv_chunk, sk)
-    # pad ragged tails (e.g. 1601 vision tokens) and mask them out
-    sq_pad = (-sq) % q_chunk
-    sk_pad = (-sk) % kv_chunk
-    if sq_pad:
-        q = jnp.pad(q, ((0, 0), (0, sq_pad), (0, 0), (0, 0), (0, 0)))
-        q_pos = jnp.pad(q_pos, (0, sq_pad))
-    if sk_pad:
-        k = jnp.pad(k, ((0, 0), (0, sk_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sk_pad), (0, 0), (0, 0)))
-        # padded keys get position +inf-ish so the causal mask kills them;
-        # the explicit validity mask below handles the non-causal case
-        q_pos_max = jnp.iinfo(jnp.int32).max
-        k_pos = jnp.pad(k_pos, (0, sk_pad), constant_values=q_pos_max)
-    k_valid = jnp.arange(sk + sk_pad) < sk
-    sq_full, sk_full = sq + sq_pad, sk + sk_pad
-    scale = 1.0 / jnp.sqrt(jnp.array(hd, jnp.float32))
 
-    def one_q_chunk(q_blk, qpos_blk, k_all, v_all, kpos_all, kvalid_all):
-        nkc = k_all.shape[1] // kv_chunk
-        k_c = k_all.reshape(b, nkc, kv_chunk, nkv, hd)
-        v_c = v_all.reshape(b, nkc, kv_chunk, nkv, vd)
-        kp_c = kpos_all.reshape(nkc, kv_chunk)
-        kv_c = kvalid_all.reshape(nkc, kv_chunk)
+def _loop(body, carry, xs, unroll: bool):
+    """``lax.scan(body, carry, xs)``, or the same as a python loop."""
+    if not unroll:
+        return jax.lax.scan(body, carry, xs)
+    ys = []
+    for j in range(jax.tree.leaves(xs)[0].shape[0]):
+        carry, y = body(carry, jax.tree.map(lambda a, _j=j: a[_j], xs))
+        ys.append(y)
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
+
+def _kv_chunks(k, v, k_pos, k_valid, kv_chunk):
+    """Keys, values, positions and validity with a leading chunk axis."""
+    b, sk, nkv, hd = k.shape
+    n = sk // kv_chunk
+    return (jnp.moveaxis(k.reshape(b, n, kv_chunk, nkv, hd), 1, 0),
+            jnp.moveaxis(v.reshape(b, n, kv_chunk, nkv, v.shape[-1]), 1, 0),
+            k_pos.reshape(n, kv_chunk), k_valid.reshape(n, kv_chunk))
+
+
+def _unchunk(x):
+    """(n, B, chunk, ...) -> (B, n * chunk, ...)."""
+    return jnp.moveaxis(x, 0, 1).reshape(x.shape[1], -1, *x.shape[3:])
+
+
+def _chunk_scores(q_blk, k_blk, qpos_blk, kp_blk, kval_blk, c: _Chunking):
+    """f32 scores of one chunk pair (B,qc,KV,G,kc) and where they count."""
+    scale = 1.0 / jnp.sqrt(jnp.array(q_blk.shape[-1], jnp.float32))
+    s = jnp.einsum("bqkgh,bskh->bqkgs", q_blk, k_blk,
+                   preferred_element_type=jnp.float32) * scale
+    mask = kval_blk[None, :]
+    if c.causal:
+        mask = mask & (qpos_blk[:, None] >= kp_blk[None, :])
+    if c.window is not None:
+        mask = mask & (qpos_blk[:, None] - kp_blk[None, :] < c.window)
+    return s, mask[None, :, None, None, :]
+
+
+def _flash_forward(q, k, v, q_pos, k_pos, k_valid, c: _Chunking):
+    """Online-softmax forward over chunk pairs.  Returns the f32 output
+    (B,Sq,KV,G,vd) and the log-sum-exp of each query row (B,Sq,KV,G)."""
+    b, sq, nkv, g, _ = q.shape
+    vd = v.shape[-1]
+    # The positions are constants of a layer loop.  Tied to q, the masks
+    # made from them stay in the chunk loops; untied, JAX hoists their
+    # computation out of the layer loop and stores every chunk pair's mask.
+    q, q_pos, k_pos, k_valid = jax.lax.optimization_barrier(
+        (q, q_pos, k_pos, k_valid))
+
+    def one_q_chunk(q_blk, qpos_blk, kv):
         def body(carry, xs):
             m, l, acc = carry
             k_blk, v_blk, kp_blk, kval_blk = xs
-            s = jnp.einsum("bqkgh,bskh->bqkgs", q_blk, k_blk,
-                           preferred_element_type=jnp.float32) * scale
-            mask = jnp.broadcast_to(kval_blk[None, :],
-                                    (q_blk.shape[1], kv_chunk))
-            if causal:
-                mask &= qpos_blk[:, None] >= kp_blk[None, :]
-            if window is not None:
-                mask &= qpos_blk[:, None] - kp_blk[None, :] < window
-            s = jnp.where(mask[None, :, None, None, :], s, NEG_INF)
+            s, mask = _chunk_scores(q_blk, k_blk, qpos_blk, kp_blk,
+                                    kval_blk, c)
+            s = jnp.where(mask, s, NEG_INF)
             m_new = jnp.maximum(m, s.max(axis=-1))
             p = jnp.exp(s - m_new[..., None])
             corr = jnp.exp(m - m_new)
@@ -175,49 +200,153 @@ def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
         init = (jnp.full((b, qc, nkv, g), NEG_INF, jnp.float32),
                 jnp.zeros((b, qc, nkv, g), jnp.float32),
                 jnp.zeros((b, qc, nkv, g, vd), jnp.float32))
-        if unroll:
-            carry = init
-            for j in range(nkc):
-                carry, _ = body(carry, (k_c[:, j], v_c[:, j], kp_c[j],
-                                        kv_c[j]))
-            m, l, acc = carry
-        else:
-            (m, l, acc), _ = jax.lax.scan(
-                body, init,
-                (jnp.moveaxis(k_c, 1, 0), jnp.moveaxis(v_c, 1, 0), kp_c,
-                 kv_c))
-        return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+        (m, l, acc), _ = _loop(body, init, kv, c.unroll)
+        l = jnp.maximum(l, 1e-30)
+        return acc / l[..., None], m + jnp.log(l)
 
-    nqc = sq_full // q_chunk
-    q_c = q.reshape(b, nqc, q_chunk, nkv, g, hd)
-    qp_c = q_pos.reshape(nqc, q_chunk)
-
-    if unroll and not (causal_skip and causal):
-        outs = [one_q_chunk(q_c[:, i], qp_c[i], k, v, k_pos, k_valid)
-                for i in range(nqc)]
-        out = jnp.stack(outs, axis=1).reshape(b, sq_full, nkv, g, vd)
-        return out[:, :sq]
-
-    if causal_skip and causal:
-        # python loop over q chunks with exact KV extent per chunk
+    nqc = sq // c.q_chunk
+    q_c = q.reshape(b, nqc, c.q_chunk, nkv, g, q.shape[-1])
+    qp_c = q_pos.reshape(nqc, c.q_chunk)
+    if c.unroll or c.causal_skip:
         outs = []
         for i in range(nqc):
-            hi = (i + 1) * q_chunk
-            lo = 0
-            if window is not None:
-                lo = max(0, (i * q_chunk - int(window)) // kv_chunk * kv_chunk)
-            hi = min(((hi + kv_chunk - 1) // kv_chunk) * kv_chunk, sk_full)
-            outs.append(one_q_chunk(q_c[:, i], qp_c[i], k[:, lo:hi],
-                                    v[:, lo:hi], k_pos[lo:hi],
-                                    k_valid[lo:hi]))
-        out = jnp.stack(outs, axis=1).reshape(b, sq_full, nkv, g, vd)
-        return out[:, :sq]
+            lo, hi = c.kv_extent(i, k.shape[1])
+            outs.append(one_q_chunk(q_c[:, i], qp_c[i], _kv_chunks(
+                k[:, lo:hi], v[:, lo:hi], k_pos[lo:hi], k_valid[lo:hi],
+                c.kv_chunk)))
+        o, lse = (jnp.stack(a, axis=1) for a in zip(*outs))
+    else:
+        kv = _kv_chunks(k, v, k_pos, k_valid, c.kv_chunk)
+        o, lse = jax.lax.map(lambda xs: one_q_chunk(xs[0], xs[1], kv),
+                             (jnp.moveaxis(q_c, 1, 0), qp_c))
+        o, lse = jnp.moveaxis(o, 0, 1), jnp.moveaxis(lse, 0, 1)
+    return o.reshape(b, sq, nkv, g, vd), lse.reshape(b, sq, nkv, g)
 
-    out = jax.lax.map(
-        lambda xs: one_q_chunk(xs[0], xs[1], k, v, k_pos, k_valid),
-        (jnp.moveaxis(q_c, 1, 0), qp_c))
-    out = jnp.moveaxis(out, 0, 1).reshape(b, sq_full, nkv, g, vd)
-    return out[:, :sq]
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash(q, k, v, q_pos, k_pos, k_valid, c: _Chunking):
+    return _flash_forward(q, k, v, q_pos, k_pos, k_valid, c)[0].astype(
+        q.dtype)
+
+
+def _flash_fwd(q, k, v, q_pos, k_pos, k_valid, c: _Chunking):
+    o, lse = _flash_forward(q, k, v, q_pos, k_pos, k_valid, c)
+    return o.astype(q.dtype), (q, k, v, q_pos, k_pos, k_valid, o, lse)
+
+
+def _flash_bwd(c: _Chunking, res, do):
+    """FlashAttention-2 backward: each chunk pair's scores are recomputed
+    from q, k and the saved log-sum-exp, never read back.  A query row that
+    sees no key (only padding rows do) gets no gradient."""
+    q, k, v, q_pos, k_pos, k_valid, o, lse = res
+    b, sq, nkv, g, hd = q.shape
+    scale = 1.0 / jnp.sqrt(jnp.array(hd, jnp.float32))
+    delta = jnp.sum(do.astype(jnp.float32) * o, axis=-1)  # rowsum(dO * O)
+
+    def one_q_chunk(q_blk, qpos_blk, do_blk, lse_blk, delta_blk, kv):
+        def body(dq, xs):
+            k_blk, v_blk, kp_blk, kval_blk = xs
+            s, mask = _chunk_scores(q_blk, k_blk, qpos_blk, kp_blk,
+                                    kval_blk, c)
+            p = jnp.where(mask, jnp.exp(s - lse_blk[..., None]), 0.0)
+            dv = jnp.einsum("bqkgs,bqkgh->bskh", p.astype(v_blk.dtype),
+                            do_blk, preferred_element_type=jnp.float32)
+            dp = jnp.einsum("bqkgh,bskh->bqkgs", do_blk, v_blk,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_blk[..., None]) * scale
+            dq = dq + jnp.einsum("bqkgs,bskh->bqkgh", ds, k_blk,
+                                 preferred_element_type=jnp.float32)
+            dk = jnp.einsum("bqkgs,bqkgh->bskh", ds, q_blk,
+                            preferred_element_type=jnp.float32)
+            return dq, (dk, dv)
+
+        dq0 = jnp.zeros(q_blk.shape, jnp.float32)
+        return _loop(body, dq0, kv, c.unroll)
+
+    nqc = sq // c.q_chunk
+
+    def chunked(a):  # (B, Sq, ...) -> (B, nqc, q_chunk, ...)
+        return a.reshape(b, nqc, c.q_chunk, *a.shape[2:])
+
+    q_c, do_c, lse_c, delta_c = map(chunked, (q, do, lse, delta))
+    qp_c = q_pos.reshape(nqc, c.q_chunk)
+    if c.unroll or c.causal_skip:
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dv = jnp.zeros(v.shape, jnp.float32)
+        dqs = []
+        for i in range(nqc):
+            lo, hi = c.kv_extent(i, k.shape[1])
+            dq_i, (dk_i, dv_i) = one_q_chunk(
+                q_c[:, i], qp_c[i], do_c[:, i], lse_c[:, i], delta_c[:, i],
+                _kv_chunks(k[:, lo:hi], v[:, lo:hi], k_pos[lo:hi],
+                           k_valid[lo:hi], c.kv_chunk))
+            dk = dk.at[:, lo:hi].add(_unchunk(dk_i))
+            dv = dv.at[:, lo:hi].add(_unchunk(dv_i))
+            dqs.append(dq_i)
+        dq = jnp.stack(dqs, axis=1)
+    else:
+        kv = _kv_chunks(k, v, k_pos, k_valid, c.kv_chunk)
+
+        def q_body(acc, xs):
+            dq_i, (dk_i, dv_i) = one_q_chunk(*xs, kv)
+            return (acc[0] + dk_i, acc[1] + dv_i), dq_i
+
+        acc0 = (jnp.zeros(kv[0].shape, jnp.float32),
+                jnp.zeros(kv[1].shape, jnp.float32))
+        (dk, dv), dq = jax.lax.scan(
+            q_body, acc0, (jnp.moveaxis(q_c, 1, 0), qp_c,
+                           *(jnp.moveaxis(a, 1, 0)
+                             for a in (do_c, lse_c, delta_c))))
+        dk, dv, dq = _unchunk(dk), _unchunk(dv), jnp.moveaxis(dq, 0, 1)
+    return (dq.reshape(q.shape).astype(q.dtype), dk.astype(k.dtype),
+            dv.astype(v.dtype), None, None, None)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
+                         q_chunk=_Q_CHUNK, kv_chunk=_KV_CHUNK,
+                         causal_skip: bool = False, unroll: bool = False):
+    """Flash-style online-softmax attention, pure jnp, with its own backward.
+
+    q: (B,Sq,KV,G,hd); k,v: (B,Sk,KV,hd); q_pos: (Sq,), k_pos: (Sk,).
+    This is the train path's long-sequence attention.  The forward keeps
+    only the output and one log-sum-exp per query row; the backward
+    (``_flash_bwd``) loops over the same chunk pairs and recomputes each
+    pair's scores, so no per-chunk score tensor is saved for it.
+
+    ``causal_skip``: unroll the q-chunk loop in python and slice the KV range
+    each q chunk can actually see (exact-causal FLOPs; bigger HLO).  Default
+    is a uniform double-scan (2x the causal FLOPs, tiny HLO).
+
+    ``unroll``: python loops for BOTH chunk levels, forward and backward
+    (dry-run cost mode only — XLA cost analysis visits scan bodies once, so
+    the scanned form undercounts attention FLOPs/bytes by ~nq*nk).
+    """
+    if unroll:
+        q_chunk = kv_chunk = 2048  # fewer, MXU-aligned bodies for compile
+    sq, sk = q.shape[1], k.shape[1]
+    q_chunk = min(q_chunk, sq)
+    kv_chunk = min(kv_chunk, sk)
+    # pad ragged tails (e.g. 1601 vision tokens) and mask them out
+    sq_pad = (-sq) % q_chunk
+    sk_pad = (-sk) % kv_chunk
+    if sq_pad:
+        q = jnp.pad(q, ((0, 0), (0, sq_pad), (0, 0), (0, 0), (0, 0)))
+        q_pos = jnp.pad(q_pos, (0, sq_pad))
+    if sk_pad:
+        k = jnp.pad(k, ((0, 0), (0, sk_pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, sk_pad), (0, 0), (0, 0)))
+        # padded keys get position +inf-ish so the causal mask kills them;
+        # the explicit validity mask handles the non-causal case
+        q_pos_max = jnp.iinfo(jnp.int32).max
+        k_pos = jnp.pad(k_pos, (0, sk_pad), constant_values=q_pos_max)
+    k_valid = jnp.arange(sk + sk_pad) < sk
+    c = _Chunking(causal=causal, window=window, q_chunk=q_chunk,
+                  kv_chunk=kv_chunk, causal_skip=causal_skip and causal,
+                  unroll=unroll)
+    return _flash(q, k, v, q_pos, k_pos, k_valid, c)[:, :sq]
 
 
 def multihead_attention(q, k, v, *, q_pos, k_pos, causal, window=None,

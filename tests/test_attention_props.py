@@ -1,6 +1,8 @@
-"""Attention-path properties: flash==plain, causal-skip==uniform scan,
-RoPE norm preservation & relative-position property, MLA absorption."""
+"""Attention-path properties: flash==plain (outputs and gradients),
+causal-skip==uniform scan, RoPE norm preservation & relative-position
+property, MLA absorption."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +12,8 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import smoke_config
-from repro.models.attention import (_flash_attention_jnp, _group_q,
+from repro.models.attention import (_Chunking, _flash_attention_jnp,
+                                    _flash_forward, _group_q,
                                     _plain_attention, mla_forward,
                                     multihead_attention)
 from repro.models.modules import apply_rope
@@ -129,3 +132,65 @@ def test_softmax_rows_sum_to_one_under_padding():
     out2 = multihead_attention(q, k2[:, :1601], v2[:, :1601], q_pos=pos_q,
                                k_pos=pos_k, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(out2), atol=1e-5)
+
+
+def _grads(attn, q, k, v, w):
+    """dq, dk, dv of sum(attn(q, k, v) * w)."""
+    return jax.grad(lambda *a: jnp.sum(attn(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+
+
+def _assert_grads_close(got, want):
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-4 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("sq,sk,h,kv,d,vd,causal,window,opts", [
+    (256, 256, 4, 2, 32, 32, True, None, {}),
+    (256, 256, 4, 2, 32, 32, True, 100, {}),
+    (256, 256, 4, 2, 32, 32, True, 100, {"causal_skip": True}),
+    (2100, 2100, 2, 1, 16, 16, True, 700, {"unroll": True}),
+    (128, 300, 4, 4, 32, 32, False, None, {}),
+    (200, 200, 4, 2, 32, 32, True, None, {}),
+    (256, 256, 8, 2, 32, 32, True, None, {}),
+    (256, 256, 4, 2, 48, 32, True, None, {}),
+], ids=["causal", "causal_window", "causal_skip", "unroll",
+        "noncausal_ragged_sk", "ragged_sq", "gqa_g4", "mla_vd_ne_hd"])
+def test_flash_gradients_equal_plain(sq, sk, h, kv, d, vd, causal, window,
+                                     opts):
+    """The chunked path's own backward gives the plain path's gradients."""
+    key = jax.random.PRNGKey(5)
+    q, k, v = _qkv(key, 2 if sq < 2048 else 1, sq, sk, h, kv, d, vd)
+    qg = _group_q(q, kv)
+    qp = jnp.arange(sk - sq, sk) if causal else jnp.arange(sq)
+    kp = jnp.arange(sk)
+    w = jax.random.normal(jax.random.fold_in(key, 3), qg.shape[:-1] + (vd,))
+    plain = functools.partial(_plain_attention, q_pos=qp, k_pos=kp,
+                              causal=causal, window=window,
+                              logit_dtype=jnp.float32)
+    flash = functools.partial(_flash_attention_jnp, q_pos=qp, k_pos=kp,
+                              causal=causal, window=window, q_chunk=64,
+                              kv_chunk=64, **opts)
+    _assert_grads_close(jax.jit(lambda *a: _grads(flash, *a))(qg, k, v, w),
+                        _grads(plain, qg, k, v, w))
+
+
+def test_flash_gradients_equal_autodiff_through_chunks():
+    """The custom backward and JAX's autodiff through the same chunked
+    forward loops agree."""
+    key = jax.random.PRNGKey(6)
+    q, k, v = _qkv(key, 2, 256, 256, 6, 2, 32)
+    qg = _group_q(q, 2)
+    pos = jnp.arange(256)
+    w = jax.random.normal(jax.random.fold_in(key, 3), qg.shape)
+    c = _Chunking(causal=True, window=100, q_chunk=64, kv_chunk=64,
+                  causal_skip=False, unroll=False)
+    autodiff = lambda q_, k_, v_: _flash_forward(
+        q_, k_, v_, pos, pos, jnp.ones(256, bool), c)[0]
+    flash = functools.partial(_flash_attention_jnp, q_pos=pos, k_pos=pos,
+                              causal=True, window=100, q_chunk=64,
+                              kv_chunk=64)
+    _assert_grads_close(jax.jit(lambda *a: _grads(flash, *a))(qg, k, v, w),
+                        jax.jit(lambda *a: _grads(autodiff, *a))(qg, k, v, w))
