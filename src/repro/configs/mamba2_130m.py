@@ -1,16 +1,17 @@
-"""mamba2-130m [ssm] — SSD (state-space duality) [arXiv:2405.21060]."""
+"""mamba2-130m [ssm] — SSD (state-space duality) [arXiv:2405.21060],
+https://huggingface.co/state-spaces/mamba2-130m."""
 from repro.core.types import ModelConfig
 
 CONFIG = ModelConfig(
     name="mamba2-130m",
     family="ssm",
-    source="arXiv:2405.21060",
+    source="arXiv:2405.21060; https://huggingface.co/state-spaces/mamba2-130m",
     num_layers=24,
     d_model=768,
     num_heads=0,
     num_kv_heads=0,
     d_ff=0,            # attention-free, no separate FFN (Mamba2 block only)
-    vocab_size=50280,
+    vocab_size=50288,  # published vocab_size 50,277 padded to a multiple of 16
     attention="none",
     ssm_state=128,
     ssm_expand=2,

@@ -13,6 +13,10 @@ Projections are kept as separate tensors (z / x / B / C / dt and per-stream
 convs) instead of one fused ``in_proj`` so the tensor-parallel planner can
 shard the head-structured ones (z, x, dt, out) over the model axis while the
 state projections (B, C — shared across heads, GQA-like) stay replicated.
+
+In ``mamba_forward`` the scan runs under ``jax.named_scope("ssd")``, inside
+the layer's ``ssm`` block scope (``models.transformer``): a name in the HLO
+metadata only, by which the benchmark reads the scan's device time.
 """
 from __future__ import annotations
 
@@ -158,8 +162,9 @@ def mamba_forward(p: dict, cfg: ModelConfig, xin: jax.Array, *,
         (xin @ p["dt_proj"]).astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
     xh = x.astype(jnp.float32).reshape(*x.shape[:2], h, hd)
-    y, _ = ssd_chunked(xh, dt, a, b.astype(jnp.float32),
-                       c.astype(jnp.float32), chunk=chunk)
+    with jax.named_scope("ssd"):
+        y, _ = ssd_chunked(xh, dt, a, b.astype(jnp.float32),
+                           c.astype(jnp.float32), chunk=chunk)
     y = y + xh * p["D"][:, None]
     y = y.reshape(*xin.shape[:2], din).astype(xin.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["norm"]["scale"], cfg.norm_eps)
