@@ -215,19 +215,30 @@ def kernel_phase(shapes: dict) -> None:
     check("flash_attention", out, ref, 2e-2, 2e-2)
 
     s = shapes["ssd"]
-    x = (jax.random.normal(ks[3], (s["b"], s["h"], s["l"], s["p"])) * 0.5
+    x = (jax.random.normal(ks[3], (s["b"], s["l"], s["h"], s["p"])) * 0.5
          ).astype(bf16)
-    dt = jax.nn.softplus(jax.random.normal(ks[4], (s["b"], s["h"], s["l"])))
+    dt = jax.nn.softplus(
+        jax.random.normal(ks[4], (s["b"], s["l"], s["h"])) - 2.0)
     a = -jnp.exp(jax.random.normal(ks[5], (s["h"],)))
     bb = (jax.random.normal(ks[6], (s["b"], s["l"], s["n"])) * 0.3
           ).astype(bf16)
     cc = (jax.random.normal(ks[7], (s["b"], s["l"], s["n"])) * 0.3
           ).astype(bf16)
-    out = ssd_scan(x, dt, a, bb, cc, chunk=s["chunk"])
+
+    def scan_and_grads(f):
+        def loss(*v):
+            y, last = f(*v, chunk=s["chunk"])
+            return jnp.sum(jnp.sin(y)) + jnp.sum(last), y
+        grads, y = jax.grad(loss, argnums=range(5), has_aux=True)(
+            x, dt, a, bb, cc)
+        return (y,) + grads
+
+    got = jax.jit(lambda: scan_and_grads(ssd_scan))()
     with hi():
-        ref = ssd_scan_ref(x, dt, a, bb, cc, chunk=s["chunk"])
-    scale = max(float(jnp.abs(ref.astype(jnp.float32)).max()), 1.0)
-    check("ssd_scan", out, ref, 3e-2, 3e-2, scale=scale)
+        want = jax.jit(lambda: scan_and_grads(ssd_scan_ref))()
+    for name, g_, w_ in zip(("y", "dx", "ddt", "da", "db", "dc"), got, want):
+        scale = max(float(jnp.abs(w_.astype(jnp.float32)).max()), 1.0)
+        check(f"ssd_scan {name}", g_, w_, 3e-2, 3e-2, scale=scale)
 
     g = shapes["gmm"]
     xg = jax.random.normal(ks[0], (g["e"], g["c"], g["d"]), bf16)

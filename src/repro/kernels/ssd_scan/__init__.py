@@ -1,1 +1,1 @@
-from repro.kernels.ssd_scan.ops import ssd_scan  # noqa: F401
+from repro.kernels.ssd_scan.ops import fits, ssd_scan  # noqa: F401

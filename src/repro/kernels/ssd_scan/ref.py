@@ -1,17 +1,3 @@
-"""Pure-jnp oracle for the SSD scan kernel: the model-level chunked SSD
-from repro.models.ssm, re-laid-out to the kernel's (B,H,L,P) convention."""
-from __future__ import annotations
-
-import jax.numpy as jnp
-
-from repro.models.ssm import ssd_chunked
-
-
-def ssd_scan_ref(x, dt, a, b, c, *, chunk: int = 128):
-    """x: (B,H,L,P); dt: (B,H,L); a: (H,); b,c: (B,L,N)."""
-    xm = jnp.moveaxis(x, 1, 2)      # (B,L,H,P)
-    dtm = jnp.moveaxis(dt, 1, 2)    # (B,L,H)
-    y, _ = ssd_chunked(xm.astype(jnp.float32), dtm.astype(jnp.float32),
-                       a.astype(jnp.float32), b.astype(jnp.float32),
-                       c.astype(jnp.float32), chunk=chunk)
-    return jnp.moveaxis(y, 2, 1).astype(x.dtype)
+"""Pure-jnp oracle for the SSD scan kernels: the model-level chunked SSD of
+``repro.models.ssm``, in the same (B, L, H, P) layout."""
+from repro.models.ssm import ssd_chunked_jnp as ssd_scan_ref  # noqa: F401

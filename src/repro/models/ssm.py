@@ -5,9 +5,17 @@ The GPU reference implementation relies on a fused selective-scan CUDA kernel
 the TPU-idiomatic equivalent is the *chunked dual form* of SSD
 [arXiv:2405.21060, Sec. 6]: intra-chunk work becomes dense (Q x Q) and
 (Q x N) matmuls that map onto the MXU, and only the O(L/Q) inter-chunk state
-recurrence is sequential (``lax.scan``).  ``repro.kernels.ssd_scan`` provides
-the Pallas kernel for the intra-chunk part; this module is the pure-jnp
-model-level implementation (also the kernel's oracle).
+recurrence is sequential.  Two implementations of the scan, one algorithm:
+
+* on the TPU, ``repro.kernels.ssd_scan``: a Pallas forward and backward
+  joined by a ``jax.custom_vjp``, each chunk's (Q x Q) decay matrices and
+  their gradients in VMEM only;
+* everywhere else, and for shapes the kernels do not tile,
+  ``ssd_chunked_jnp`` (XLA's fusions; also the kernels' oracle).
+
+``ssd_chunked`` picks one from the shapes and, when the program is lowered,
+from the target platform (``lax.platform_dependent``); a program split over
+a mesh of several devices keeps the jnp scan (``mamba_forward``).
 
 Projections are kept as separate tensors (z / x / B / C / dt and per-stream
 convs) instead of one fused ``in_proj`` so the tensor-parallel planner can
@@ -97,7 +105,24 @@ def ssd_chunked(x, dt, a, b, c, *, chunk: int = DEFAULT_CHUNK, h0=None):
 
     x: (B, L, H, P) f32; dt: (B, L, H) f32 (post-softplus);
     a: (H,) negative decay rates; b, c: (B, L, N) (single group, broadcast
-    over heads).  Returns (y (B,L,H,P), h_final (B,H,P,N))."""
+    over heads).  Returns (y (B,L,H,P), h_final (B,H,P,N)).
+
+    Where the Pallas kernels tile the shapes (``ssd_scan.fits``), a program
+    lowered for the TPU runs them (forward and backward, ``ssd_scan``); any
+    other platform, and every shape they do not tile, runs
+    ``ssd_chunked_jnp``.  The choice is made when the program is lowered."""
+    from repro.kernels import ssd_scan   # Pallas, imported where it runs
+    if not ssd_scan.fits(x.shape, b.shape[-1], chunk, x.dtype.itemsize):
+        return ssd_chunked_jnp(x, dt, a, b, c, chunk=chunk, h0=h0)
+    return jax.lax.platform_dependent(
+        x, dt, a, b, c, h0,
+        tpu=lambda *v: ssd_scan.ssd_scan(*v[:5], chunk=chunk, h0=v[5]),
+        default=lambda *v: ssd_chunked_jnp(*v[:5], chunk=chunk, h0=v[5]))
+
+
+def ssd_chunked_jnp(x, dt, a, b, c, *, chunk: int = DEFAULT_CHUNK, h0=None):
+    """``ssd_chunked`` in plain jnp: XLA's fusions on any platform, and the
+    Pallas kernels' oracle."""
     bsz, l, h, p = x.shape
     n = b.shape[-1]
     q = min(chunk, l)
@@ -150,8 +175,10 @@ def ssd_chunked(x, dt, a, b, c, *, chunk: int = DEFAULT_CHUNK, h0=None):
 
 
 def mamba_forward(p: dict, cfg: ModelConfig, xin: jax.Array, *,
-                  chunk: int = DEFAULT_CHUNK) -> jax.Array:
-    """xin: (B, L, d) -> (B, L, d)."""
+                  chunk: int = DEFAULT_CHUNK, ctx=None) -> jax.Array:
+    """xin: (B, L, d) -> (B, L, d).  Under a ``ctx`` whose mesh holds more
+    than one device the scan stays in jnp: GSPMD cannot split the kernels,
+    and would gather their operands whole onto every device."""
     din, n, h = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_num_heads
     hd = cfg.ssm_head_dim
     z = xin @ p["z_proj"]
@@ -162,9 +189,11 @@ def mamba_forward(p: dict, cfg: ModelConfig, xin: jax.Array, *,
         (xin @ p["dt_proj"]).astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
     xh = x.astype(jnp.float32).reshape(*x.shape[:2], h, hd)
+    mesh = getattr(ctx, "mesh", None)
+    scan = ssd_chunked if mesh is None or mesh.size == 1 else ssd_chunked_jnp
     with jax.named_scope("ssd"):
-        y, _ = ssd_chunked(xh, dt, a, b.astype(jnp.float32),
-                           c.astype(jnp.float32), chunk=chunk)
+        y, _ = scan(xh, dt, a, b.astype(jnp.float32), c.astype(jnp.float32),
+                    chunk=chunk)
     y = y + xh * p["D"][:, None]
     y = y.reshape(*xin.shape[:2], din).astype(xin.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["norm"]["scale"], cfg.norm_eps)

@@ -135,7 +135,7 @@ def _apply_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, x, positions,
             h = attn.cross_attention_forward(lp["mixer"], cfg, h, context,
                                              unroll=unroll)
         else:
-            h = ssm.mamba_forward(lp["mixer"], cfg, h)
+            h = ssm.mamba_forward(lp["mixer"], cfg, h, ctx=ctx)
         x = x + h
         x = _constrain(x, ctx, "act_spec")
     aux = jnp.zeros((), jnp.float32)

@@ -1,4 +1,6 @@
 """Per-kernel allclose sweeps vs the pure-jnp oracles (interpret=True)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.moe_gmm.ops import moe_gmm
 from repro.kernels.moe_gmm.ref import moe_gmm_ref
+from repro.kernels.ssd_scan.ops import fits as ssd_fits
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_scan_ref
 
@@ -50,52 +53,106 @@ def test_flash_attention_block_shapes(block):
                                rtol=2e-5)
 
 
+# (B, H, L, P, N, Q): three or more chunks, several heads; two heads to a
+# 128-lane group (mamba2's head size) or one (jamba's)
+SSD_SHAPES = [
+    (1, 4, 384, 64, 128, 128),
+    (2, 2, 512, 128, 128, 128),
+    (1, 4, 768, 64, 128, 256),   # mamba2-130m's chunk and state
+]
+
+
+def _ssd_inputs(b, h, l, p, n, dtype, key=0):
+    ks = jax.random.split(jax.random.PRNGKey(key + l + p), 6)
+    x = (jax.random.normal(ks[0], (b, l, h, p)) * 0.5).astype(dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.77))
+    bb = (jax.random.normal(ks[3], (b, l, n)) * 0.3).astype(dtype)
+    cc = (jax.random.normal(ks[4], (b, l, n)) * 0.3).astype(dtype)
+    h0 = jax.random.normal(ks[5], (b, h, p, n)) * 0.1
+    return x, dt, a, bb, cc, h0
+
+
+def _rel(got, want):
+    got, want = (np.asarray(v, np.float64).ravel() for v in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+# The kernels take every product in one bfloat16 pass (as the TPU takes the
+# jnp scan's float32 einsums); the oracle runs in float32 on the CPU.
+SSD_TOL = 1e-2
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("b,h,l,p,n,chunk", [
-    (1, 2, 256, 64, 32, 64),
-    (2, 4, 512, 64, 128, 128),  # mamba2-130m-like state
-    (1, 2, 256, 128, 64, 256),  # jamba-like head dim
-])
+@pytest.mark.parametrize("b,h,l,p,n,chunk", SSD_SHAPES)
 def test_ssd_scan_sweep(b, h, l, p, n, chunk, dtype):
-    key = jax.random.PRNGKey(l + p)
-    x = (jax.random.normal(key, (b, h, l, p)) * 0.5).astype(dtype)
-    dt = jax.nn.softplus(
-        jax.random.normal(jax.random.fold_in(key, 1), (b, h, l))).astype(
-        jnp.float32)
-    a = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (h,)))
-    bb = (jax.random.normal(jax.random.fold_in(key, 3), (b, l, n)) * 0.3
-          ).astype(dtype)
-    cc = (jax.random.normal(jax.random.fold_in(key, 4), (b, l, n)) * 0.3
-          ).astype(dtype)
-    out = ssd_scan(x, dt, a, bb, cc, chunk=chunk)
-    ref = ssd_scan_ref(x, dt, a, bb, cc, chunk=chunk)
-    scale = max(float(jnp.abs(ref.astype(jnp.float32)).max()), 1.0)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32) / scale,
-        np.asarray(ref, np.float32) / scale,
-        atol=3e-2 if dtype == jnp.bfloat16 else 3e-5, rtol=3e-2)
+    """Forward: y and the final state, from a given initial state."""
+    x, dt, a, bb, cc, h0 = _ssd_inputs(b, h, l, p, n, dtype)
+    assert ssd_fits(x.shape, n, chunk, x.dtype.itemsize)
+    y, last = ssd_scan(x, dt, a, bb, cc, chunk=chunk, h0=h0)
+    y_ref, last_ref = ssd_scan_ref(x, dt, a, bb, cc, chunk=chunk, h0=h0)
+    assert y.dtype == y_ref.dtype and last.dtype == last_ref.dtype
+    assert _rel(y, y_ref) < SSD_TOL
+    assert _rel(last, last_ref) < SSD_TOL
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,h,l,p,n,chunk", SSD_SHAPES)
+def test_ssd_scan_gradients(b, h, l, p, n, chunk, dtype):
+    """Backward: the gradient of every input, x, dt, a, B, C and the
+    initial state, against ``jax.vjp`` of the jnp scan, with cotangents on
+    both y and the final state."""
+    args = _ssd_inputs(b, h, l, p, n, dtype)
+    ks = jax.random.split(jax.random.PRNGKey(7), 2)
+
+    def vjp(f):
+        out, pull = jax.vjp(
+            lambda *v: f(*v[:5], chunk=chunk, h0=v[5]), *args)
+        cts = tuple(jax.random.normal(k, o.shape, o.dtype)
+                    for k, o in zip(ks, out))
+        return pull(cts)
+
+    for name, got, want in zip(("x", "dt", "a", "b", "c", "h0"),
+                               vjp(ssd_scan), vjp(ssd_scan_ref)):
+        assert got.dtype == want.dtype, name
+        assert _rel(got, want) < SSD_TOL, (name, _rel(got, want))
+
+
+@pytest.mark.parametrize("head_dim, devices, kernel", [
+    (64, 1, True),     # mamba2-130m's widths on one device
+    (64, 4, False),    # a mesh of several devices keeps the jnp scan
+    (48, 1, False),    # heads the kernels' lane groups do not tile
+])
+def test_mamba_scan_dispatch(head_dim, devices, kernel):
+    """``mamba_forward`` stages the platform switch to the kernels only for
+    shapes they tile and a program on one device."""
+    from types import SimpleNamespace
+
+    from repro.configs import get_config
+    from repro.models.ssm import init_mamba, mamba_forward
+    cfg = dataclasses.replace(get_config("mamba2-130m"),
+                              ssm_head_dim=head_dim)
+    params = jax.eval_shape(lambda k: init_mamba(k, cfg, jnp.float32),
+                            jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct((1, 256, cfg.d_model), jnp.float32)
+    ctx = SimpleNamespace(mesh=SimpleNamespace(size=devices))
+    jaxpr = jax.make_jaxpr(lambda p_, x_: mamba_forward(p_, cfg, x_, ctx=ctx))(
+        params, x)
+    assert ("platform_index" in str(jaxpr)) is kernel
 
 
 def test_ssd_scan_state_continuity():
     """Scanning 2 chunks must differ from treating them independently —
     proves the VMEM carry state crosses the chunk boundary."""
-    key = jax.random.PRNGKey(0)
-    b, h, l, p, n = 1, 1, 256, 32, 16
-    x = jax.random.normal(key, (b, h, l, p)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(key, 1),
-                                           (b, h, l)))
-    a = -jnp.exp(jax.random.normal(jax.random.fold_in(key, 2), (h,)))
-    bb = jax.random.normal(jax.random.fold_in(key, 3), (b, l, n)) * 0.3
-    cc = jax.random.normal(jax.random.fold_in(key, 4), (b, l, n)) * 0.3
-    joint = ssd_scan(x, dt, a, bb, cc, chunk=128)
-    # independent halves
-    h1 = ssd_scan(x[:, :, :128], dt[:, :, :128], a, bb[:, :128], cc[:, :128],
-                  chunk=128)
-    h2 = ssd_scan(x[:, :, 128:], dt[:, :, 128:], a, bb[:, 128:], cc[:, 128:],
-                  chunk=128)
-    assert np.allclose(np.asarray(joint[:, :, :128]), np.asarray(h1),
+    x, dt, a, bb, cc, _ = _ssd_inputs(1, 2, 256, 64, 128, jnp.float32)
+    joint, _ = ssd_scan(x, dt, a, bb, cc, chunk=128)
+    h1, _ = ssd_scan(x[:, :128], dt[:, :128], a, bb[:, :128], cc[:, :128],
+                     chunk=128)
+    h2, _ = ssd_scan(x[:, 128:], dt[:, 128:], a, bb[:, 128:], cc[:, 128:],
+                     chunk=128)
+    assert np.allclose(np.asarray(joint[:, :128]), np.asarray(h1),
                        atol=1e-5)
-    assert not np.allclose(np.asarray(joint[:, :, 128:]), np.asarray(h2),
+    assert not np.allclose(np.asarray(joint[:, 128:]), np.asarray(h2),
                            atol=1e-3), "second chunk ignored carried state"
 
 

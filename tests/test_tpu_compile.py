@@ -21,8 +21,10 @@ from repro.core.types import MeshConfig, TrainConfig
 from repro.kernels.compress.ops import dequantize, quantize
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.moe_gmm.ops import moe_gmm
+from repro.kernels.ssd_scan.ops import fits as ssd_fits
 from repro.kernels.ssd_scan.ops import ssd_scan
 from repro.launch.mesh import make_mesh
+from repro.models.ssm import init_mamba, mamba_forward
 from repro.models.transformer import init_params
 from repro.optim.adamw import init_opt_state
 from repro.parallel.planner import batch_specs, make_ctx, param_specs
@@ -70,15 +72,47 @@ def test_flash_attention_compiles_at_qwen2_widths(one_chip):
                             q, kv, kv))
 
 
+def _kernel_op_names(compiled):
+    """The ``op_name`` of every Mosaic kernel of a compiled program."""
+    return [m.group(1) for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(r'op_name="([^"]*)"', line)] if m]
+
+
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
+    """The SSD forward and backward kernels at the mamba2 cell's shapes:
+    B 16, L 2048, H 24, P 64, N 128, chunk 256."""
     cfg = get_config("mamba2-130m")
-    b, l, h = 2, 1024, cfg.ssm_num_heads
-    args = (_shape((b, h, l, cfg.ssm_head_dim), BF16, one_chip),
-            _shape((b, h, l), jnp.float32, one_chip),
-            _shape((h,), jnp.float32, one_chip),
-            _shape((b, l, cfg.ssm_state), BF16, one_chip),
-            _shape((b, l, cfg.ssm_state), BF16, one_chip))
+    b, l, h, n = 16, 2048, cfg.ssm_num_heads, cfg.ssm_state
+    f32 = jnp.float32
+    args = (_shape((b, l, h, cfg.ssm_head_dim), f32, one_chip),
+            _shape((b, l, h), f32, one_chip), _shape((h,), f32, one_chip),
+            _shape((b, l, n), f32, one_chip), _shape((b, l, n), f32, one_chip))
+    assert ssd_fits(args[0].shape, n, 256)
     _assert_kernel(_compile(lambda *a: ssd_scan(*a, chunk=256), *args))
+
+    def loss(*a):
+        y, last = ssd_scan(*a, chunk=256)
+        return jnp.sum(y * y) + jnp.sum(last)
+
+    grad = _compile(jax.grad(loss, argnums=range(5)), *args)
+    assert len(_kernel_op_names(grad)) == 2   # forward, then backward
+
+
+def test_mamba2_layer_kernels_carry_ssd_scope(one_chip):
+    """A mamba2-130m layer's gradient runs the scan through the kernels,
+    whose op names keep the ``ssd`` scope that ``ssd_ms`` reads, forward
+    and backward."""
+    cfg = get_config("mamba2-130m")
+    p = jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip),
+                     jax.eval_shape(lambda k: init_mamba(k, cfg, jnp.float32),
+                                    jax.random.PRNGKey(0)))
+    x = _shape((2, 1024, cfg.d_model), jnp.float32, one_chip)
+    grad = _compile(jax.grad(lambda p_, x_: jnp.sum(
+        mamba_forward(p_, cfg, x_) ** 2)), p, x)
+    names = _kernel_op_names(grad)
+    assert names and all(re.search(r"[/(]ssd[/)]", n) for n in names), names
+    assert {"transpose(" in n for n in names} == {True, False}, names
 
 
 def test_moe_gmm_compiles_at_dbrx_widths(one_chip):
