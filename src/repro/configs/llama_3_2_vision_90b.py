@@ -1,8 +1,9 @@
 """llama-3.2-vision-90b [vlm] — cross-attn image layers
 [hf:meta-llama/Llama-3.2-11B-Vision].
 
-Backbone only: the ViT vision encoder + projector is a stub; ``input_specs``
-provides precomputed patch embeddings (batch, patches, d_model).  100 layers
+Backbone only: the ViT vision encoder + projector is a stub;
+``repro.data.stubs.vision_patches`` provides precomputed patch embeddings
+(batch, patches, d_model).  100 layers
 with one cross-attention layer every 5th layer (20 cross-attn + 80 self-attn),
 matching the Llama-3.2-Vision interleave ratio.
 """

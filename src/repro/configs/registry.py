@@ -38,8 +38,8 @@ def list_archs() -> List[str]:
 def smoke_config(arch: str) -> ModelConfig:
     """Reduced variant of the same family: 2 layers, d_model<=512, <=4 experts.
 
-    Used by per-arch CPU smoke tests; the full config is exercised only via
-    the dry-run (ShapeDtypeStruct, no allocation).
+    Used by per-arch CPU smoke tests; full configs are exercised by the
+    planner and by compile rehearsals for a described TPU.
     """
     cfg = get_config(arch)
     d_model = min(cfg.d_model, 256)

@@ -1,8 +1,9 @@
 """seamless-m4t-medium [audio] — enc-dec, multimodal [arXiv:2308.11596].
 
 Backbone only: the mel-spectrogram + conv feature extractor frontend is a
-stub; ``input_specs`` provides precomputed frame embeddings (batch, frames,
-d_model) for the encoder, and the decoder consumes them via cross-attention.
+stub; ``repro.data.stubs.audio_frames`` provides precomputed frame embeddings
+(batch, frames, d_model) for the encoder, and the decoder consumes them via
+cross-attention.
 """
 from repro.core.types import ModelConfig
 

@@ -1,29 +1,5 @@
-"""Target hardware constants (TPU v5e) for the roofline model.
+"""Target hardware constant (TPU v5e) for the planner's compute-time model."""
 
-This container runs on CPU; these constants describe the TARGET chip used
-to convert the dry-run's compiled FLOP/byte counts into roofline seconds.
-"""
-
-PEAK_FLOPS_BF16 = 197e12     # FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW_PER_LINK = 50e9       # bytes/s per ICI link (~v5e per direction)
-ICI_LINKS_PER_CHIP = 4       # 2D torus: 4 links per chip (v5e)
-DCN_BW_PER_HOST = 25e9       # bytes/s inter-pod (per 8-chip host, approx)
-VMEM_BYTES = 128 * 2 ** 20   # ~128 MiB VMEM per core (v5e ~128MB)
-HBM_BYTES = 16 * 2 ** 30     # 16 GiB HBM per chip
-MXU_TILE = 128               # systolic array dimension
-
-
-def roofline_seconds(flops: float, hbm_bytes: float, coll_bytes: float,
-                     chips: int) -> dict:
-    """The three roofline terms (seconds) from Sec. ROOFLINE ANALYSIS.
-
-    ``flops``/``hbm_bytes`` are TOTALS across chips (cost_analysis of the
-    SPMD module is per-device; callers pass per-device numbers with
-    chips=1).  ``coll_bytes`` is the summed operand bytes of collective ops
-    per device."""
-    return {
-        "compute_s": flops / (chips * PEAK_FLOPS_BF16),
-        "memory_s": hbm_bytes / (chips * HBM_BW),
-        "collective_s": coll_bytes / (chips * ICI_BW_PER_LINK),
-    }
+# bf16 FLOP/s of one v5e chip (Google Cloud TPU v5e documentation; the
+# benchmark's peaks.json carries the same figure)
+PEAK_FLOPS_BF16 = 197e12

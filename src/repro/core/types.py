@@ -170,7 +170,7 @@ class ModelConfig:
     def layer_groups(self) -> Tuple[Tuple[Tuple[LayerSpec, ...], int], ...]:
         """Group the layer pattern into (period, repeats) so the stack can be
         built as ``scan`` over stacked params — keeps HLO size O(period), not
-        O(num_layers), which is what makes 100-layer dry-runs compile fast.
+        O(num_layers), which is what makes 100-layer configs compile fast.
         """
         specs = self.layer_specs()
         # find, over small prefixes, the smallest period that tiles the rest;

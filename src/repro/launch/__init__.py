@@ -1,1 +1,1 @@
-"""Launch layer: production mesh, multi-pod dry-run, training launcher."""
+"""Launch layer: mesh construction, decode cache shapes, training launcher."""

@@ -5,8 +5,8 @@ planner's PartitionSpecs, with no per-op ``out_sharding``.  (``jax.make_mesh``
 defaults to Explicit axes since JAX 0.7, which breaks gathers and grads whose
 operands are sharded.)
 
-Functions, not module-level constants: importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before any jax import)."""
+A function, not a module-level mesh: importing this module never touches
+jax device state (callers may set XLA_FLAGS before the first device query)."""
 from __future__ import annotations
 
 from typing import Optional, Sequence
@@ -14,8 +14,6 @@ from typing import Optional, Sequence
 import jax
 import numpy as np
 from jax.sharding import AxisType, Mesh
-
-from repro.core.types import MULTI_POD_MESH, SINGLE_POD_MESH, MeshConfig
 
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
@@ -30,12 +28,3 @@ def make_mesh(shape: Sequence[int], axis_names: Sequence[str], *,
     devs = np.asarray(devices, dtype=object).reshape(tuple(shape))
     return Mesh(devs, tuple(axis_names), axis_types=axis_types)
 
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
-    return MULTI_POD_MESH if multi_pod else SINGLE_POD_MESH

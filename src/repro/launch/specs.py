@@ -1,13 +1,10 @@
-"""ShapeDtypeStruct stand-ins for every model input (no device allocation).
-
-``input_specs`` is the dry-run's workload description: training batches,
-prefill prompts, or decode steps with their KV/SSM caches.  The long-context
-policy (which architectures decode 500k tokens natively vs. via the
-sliding-window variant) lives here as ``decode_window``.
+"""Decode-cache shapes (ShapeDtypeStructs, no device allocation) and the
+long-context policy: which architectures decode 500k tokens natively and
+which via the sliding-window variant (``decode_window``).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +21,7 @@ def decode_window(cfg: ModelConfig, shape: ShapeConfig) -> Optional[int]:
       native   — SSM (no KV), hybrid (9 attn layers, seq-sharded cache),
                  MLA (compact latent cache), archs with built-in SWA;
       variant  — full-attention dense/MoE/VLM archs run the sliding-window
-                 variant (window 8192), flagged in the roofline table.
+                 variant (window 8192).
     """
     if shape.name != "long_500k":
         return None
@@ -39,49 +36,18 @@ def uses_swa_variant(cfg: ModelConfig, shape: ShapeConfig) -> bool:
     return decode_window(cfg, shape) is not None
 
 
-def context_spec(cfg: ModelConfig, batch: int, dtype) -> Optional[Any]:
-    if cfg.is_encoder_decoder:
-        return jax.ShapeDtypeStruct((batch, cfg.num_audio_frames,
-                                     cfg.d_model), dtype)
-    if cfg.cross_attn_period:
-        return jax.ShapeDtypeStruct((batch, cfg.num_vision_tokens,
-                                     cfg.d_model), dtype)
-    return None
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig,
-                dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """Model inputs as ShapeDtypeStructs for ``.lower()``."""
-    b, s = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    if shape.kind == "train":
-        out = {
-            "tokens": jax.ShapeDtypeStruct((b, s), i32),
-            "labels": jax.ShapeDtypeStruct((b, s), i32),
-        }
-        ctxs = context_spec(cfg, b, dtype)
-        if ctxs is not None:
-            out["context"] = ctxs
-        return out
-    if shape.kind == "prefill":
-        out = {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
-        ctxs = context_spec(cfg, b, dtype)
-        if ctxs is not None:
-            out["context"] = ctxs
-        return out
-    # decode: one new token against a cache of seq_len
-    return {
-        "tokens": jax.ShapeDtypeStruct((b, 1), i32),
-        "pos": jax.ShapeDtypeStruct((), i32),
-    }
-
-
 def cache_shapes(cfg: ModelConfig, shape: ShapeConfig, params_shapes,
                  dtype=jnp.bfloat16):
     """eval_shape of the decode cache for this workload."""
     from repro.models.transformer import init_cache
     win = decode_window(cfg, shape)
-    ctx_s = context_spec(cfg, shape.global_batch, dtype)
+    ctx_s = None  # the encoder's frames or the vision tokens
+    if cfg.is_encoder_decoder:
+        ctx_s = jax.ShapeDtypeStruct(
+            (shape.global_batch, cfg.num_audio_frames, cfg.d_model), dtype)
+    elif cfg.cross_attn_period:
+        ctx_s = jax.ShapeDtypeStruct(
+            (shape.global_batch, cfg.num_vision_tokens, cfg.d_model), dtype)
 
     def build(p, c):
         return init_cache(cfg, p, shape.global_batch, shape.seq_len,

@@ -1,14 +1,12 @@
 """Attention flavours: GQA (+RoPE, QKV-bias, sliding-window), MLA, cross-attn.
 
-Three compute paths:
+Two compute paths, chosen from the sequence lengths alone:
   * plain einsum attention where Sq * Sk <= 2048^2 (short training
     sequences, smoke tests, examples);
   * flash-style chunked attention in pure jnp (two nested ``lax.scan``) for
     longer sequences, with its own FlashAttention-2 backward — O(S * chunk)
     live memory in both directions, small HLO.  It is the train path's
-    long-sequence attention and the dry-run's;
-  * the Pallas kernel in ``repro.kernels.flash_attention`` (``use_pallas``),
-    forward only: it has no backward, so training does not take it.
+    long-sequence attention.
 
 Decode attends one new token against a KV cache; sliding-window caches are
 ring buffers of ``window`` slots.
@@ -117,7 +115,6 @@ class _Chunking:
     q_chunk: int
     kv_chunk: int
     causal_skip: bool   # per-q-chunk KV extents (causal only)
-    unroll: bool        # python loops for both chunk levels
 
     def kv_extent(self, i: int, sk: int) -> Tuple[int, int]:
         """The keys ``[lo, hi)`` that q chunk ``i`` visits."""
@@ -129,17 +126,6 @@ class _Chunking:
                      // self.kv_chunk * self.kv_chunk)
         hi = ((i + 1) * self.q_chunk + self.kv_chunk - 1) // self.kv_chunk
         return lo, min(hi * self.kv_chunk, sk)
-
-
-def _loop(body, carry, xs, unroll: bool):
-    """``lax.scan(body, carry, xs)``, or the same as a python loop."""
-    if not unroll:
-        return jax.lax.scan(body, carry, xs)
-    ys = []
-    for j in range(jax.tree.leaves(xs)[0].shape[0]):
-        carry, y = body(carry, jax.tree.map(lambda a, _j=j: a[_j], xs))
-        ys.append(y)
-    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
 
 def _kv_chunks(k, v, k_pos, k_valid, kv_chunk):
@@ -200,14 +186,14 @@ def _flash_forward(q, k, v, q_pos, k_pos, k_valid, c: _Chunking):
         init = (jnp.full((b, qc, nkv, g), NEG_INF, jnp.float32),
                 jnp.zeros((b, qc, nkv, g), jnp.float32),
                 jnp.zeros((b, qc, nkv, g, vd), jnp.float32))
-        (m, l, acc), _ = _loop(body, init, kv, c.unroll)
+        (m, l, acc), _ = jax.lax.scan(body, init, kv)
         l = jnp.maximum(l, 1e-30)
         return acc / l[..., None], m + jnp.log(l)
 
     nqc = sq // c.q_chunk
     q_c = q.reshape(b, nqc, c.q_chunk, nkv, g, q.shape[-1])
     qp_c = q_pos.reshape(nqc, c.q_chunk)
-    if c.unroll or c.causal_skip:
+    if c.causal_skip:
         outs = []
         for i in range(nqc):
             lo, hi = c.kv_extent(i, k.shape[1])
@@ -261,7 +247,7 @@ def _flash_bwd(c: _Chunking, res, do):
             return dq, (dk, dv)
 
         dq0 = jnp.zeros(q_blk.shape, jnp.float32)
-        return _loop(body, dq0, kv, c.unroll)
+        return jax.lax.scan(body, dq0, kv)
 
     nqc = sq // c.q_chunk
 
@@ -270,7 +256,7 @@ def _flash_bwd(c: _Chunking, res, do):
 
     q_c, do_c, lse_c, delta_c = map(chunked, (q, do, lse, delta))
     qp_c = q_pos.reshape(nqc, c.q_chunk)
-    if c.unroll or c.causal_skip:
+    if c.causal_skip:
         dk = jnp.zeros(k.shape, jnp.float32)
         dv = jnp.zeros(v.shape, jnp.float32)
         dqs = []
@@ -307,7 +293,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
                          q_chunk=_Q_CHUNK, kv_chunk=_KV_CHUNK,
-                         causal_skip: bool = False, unroll: bool = False):
+                         causal_skip: bool = False):
     """Flash-style online-softmax attention, pure jnp, with its own backward.
 
     q: (B,Sq,KV,G,hd); k,v: (B,Sk,KV,hd); q_pos: (Sq,), k_pos: (Sk,).
@@ -319,13 +305,7 @@ def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
     ``causal_skip``: unroll the q-chunk loop in python and slice the KV range
     each q chunk can actually see (exact-causal FLOPs; bigger HLO).  Default
     is a uniform double-scan (2x the causal FLOPs, tiny HLO).
-
-    ``unroll``: python loops for BOTH chunk levels, forward and backward
-    (dry-run cost mode only — XLA cost analysis visits scan bodies once, so
-    the scanned form undercounts attention FLOPs/bytes by ~nq*nk).
     """
-    if unroll:
-        q_chunk = kv_chunk = 2048  # fewer, MXU-aligned bodies for compile
     sq, sk = q.shape[1], k.shape[1]
     q_chunk = min(q_chunk, sq)
     kv_chunk = min(kv_chunk, sk)
@@ -344,31 +324,14 @@ def _flash_attention_jnp(q, k, v, *, q_pos, k_pos, causal, window,
         k_pos = jnp.pad(k_pos, (0, sk_pad), constant_values=q_pos_max)
     k_valid = jnp.arange(sk + sk_pad) < sk
     c = _Chunking(causal=causal, window=window, q_chunk=q_chunk,
-                  kv_chunk=kv_chunk, causal_skip=causal_skip and causal,
-                  unroll=unroll)
+                  kv_chunk=kv_chunk, causal_skip=causal_skip and causal)
     return _flash(q, k, v, q_pos, k_pos, k_valid, c)[:, :sq]
 
 
 def multihead_attention(q, k, v, *, q_pos, k_pos, causal, window=None,
-                        causal_skip=False, unroll=False,
-                        use_pallas=False):
-    """Dispatch between plain / flash-jnp / Pallas paths.
-    q: (B,Sq,H,hd) ungrouped.  ``use_pallas`` runs the flash-attention
-    kernel (compiled on TPU, interpreted on CPU) and raises for shapes it
-    does not cover rather than falling back."""
-    if use_pallas:
-        if not (q.shape[1] == k.shape[1] and q.shape[-1] == v.shape[-1]
-                and q.shape[1] % 128 == 0):
-            raise ValueError(
-                f"Pallas flash attention needs self-attention with Sq == Sk "
-                f"a multiple of 128 and equal q/v head dims; got q "
-                f"{q.shape}, k {k.shape}, v {v.shape}")
-        # Layout: (B,S,H,D) -> (B,H,S,D); contiguous positions assumed.
-        from repro.kernels.flash_attention.ops import flash_attention
-        out = flash_attention(
-            jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), causal=causal, window=window)
-        return jnp.swapaxes(out, 1, 2)
+                        causal_skip=False):
+    """Plain attention where Sq * Sk <= 2048^2, chunked flash beyond.
+    q: (B,Sq,H,hd) ungrouped."""
     nkv = k.shape[2]
     qg = _group_q(q, nkv)
     if q.shape[1] * k.shape[1] <= _PLAIN_ATTN_MAX_SEQ ** 2:
@@ -378,7 +341,7 @@ def multihead_attention(q, k, v, *, q_pos, k_pos, causal, window=None,
     else:
         out = _flash_attention_jnp(qg, k, v, q_pos=q_pos, k_pos=k_pos,
                                    causal=causal, window=window,
-                                   causal_skip=causal_skip, unroll=unroll)
+                                   causal_skip=causal_skip)
     b, s = q.shape[:2]
     return out.reshape(b, s, q.shape[2], v.shape[-1])  # out head dim = v's
 
@@ -401,8 +364,7 @@ def _project_qkv(p: dict, cfg: ModelConfig, x, kv_x=None):
 
 
 def gqa_forward(p: dict, cfg: ModelConfig, x, positions, *,
-                window=None, causal_skip=False, unroll=False,
-                use_pallas=False):
+                window=None, causal_skip=False):
     """x: (B,S,d); positions: (S,) absolute positions."""
     q, k, v = _project_qkv(p, cfg, x)
     q = apply_rope(q, positions, cfg.rope_theta)
@@ -410,20 +372,17 @@ def gqa_forward(p: dict, cfg: ModelConfig, x, positions, *,
     win = window if window is not None else cfg.sliding_window
     out = multihead_attention(q, k, v, q_pos=positions, k_pos=positions,
                               causal=True, window=win,
-                              causal_skip=causal_skip, unroll=unroll,
-                              use_pallas=use_pallas)
+                              causal_skip=causal_skip)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
-def cross_attention_forward(p: dict, cfg: ModelConfig, x, context,
-                            unroll=False):
+def cross_attention_forward(p: dict, cfg: ModelConfig, x, context):
     """Cross-attention: queries from x (B,S,d), keys/values from context
     (B,T,d).  No RoPE, no causal mask (Llama-3.2-Vision / enc-dec style)."""
     q, k, v = _project_qkv(p, cfg, x, kv_x=context)
     s_pos = jnp.arange(x.shape[1])
     t_pos = jnp.arange(context.shape[1])
-    out = multihead_attention(q, k, v, q_pos=s_pos, k_pos=t_pos, causal=False,
-                              unroll=unroll)
+    out = multihead_attention(q, k, v, q_pos=s_pos, k_pos=t_pos, causal=False)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     if "gate_attn" in p:
         out = out * jnp.tanh(p["gate_attn"])
@@ -554,7 +513,7 @@ def _mla_latent(p: dict, cfg: ModelConfig, x, positions):
 
 
 def mla_forward(p: dict, cfg: ModelConfig, x, positions, *,
-                window=None, causal_skip=False, unroll=False):
+                window=None, causal_skip=False):
     """Naive (decompressed) MLA for train/prefill: materialize per-head K/V."""
     hd = cfg.resolved_head_dim
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
@@ -568,7 +527,7 @@ def mla_forward(p: dict, cfg: ModelConfig, x, positions, *,
         axis=-1)
     out = multihead_attention(q, k, v, q_pos=positions, k_pos=positions,
                               causal=True, window=window,
-                              causal_skip=causal_skip, unroll=unroll)
+                              causal_skip=causal_skip)
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 

@@ -2,9 +2,9 @@
 
 Layer stacks are built as ``lax.scan`` over parameter-stacked *layer groups*
 (``ModelConfig.layer_groups``): HLO size stays O(period), so 100-layer
-configs lower and compile quickly in the multi-pod dry-run.  Heterogeneous
-patterns (Jamba's 1 attn : 7 mamba, Llama-Vision's 4 self : 1 cross) become
-a short unrolled period inside the scan body.
+configs lower and compile quickly.  Heterogeneous patterns (Jamba's 1 attn :
+7 mamba, Llama-Vision's 4 self : 1 cross) become a short unrolled period
+inside the scan body.
 
 Each block runs under a ``jax.named_scope`` named by its kind: ``attention``,
 ``ssm`` or ``cross_attention`` (norm, mixer, residual), ``ffn`` or ``moe``
@@ -118,22 +118,19 @@ def _ffn_scope(spec: LayerSpec) -> str:
 
 def _apply_layer(lp: dict, spec: LayerSpec, cfg: ModelConfig, x, positions,
                  context, ctx, window, cross_lp=None):
-    unroll = _flag(ctx, "unroll_layers")  # dry-run cost mode: see attention
     with jax.named_scope(_mixer_scope(spec)):
         h = rms_norm(x, lp["norm1"]["scale"], cfg.norm_eps)
         if spec.mixer == "attn":
             if cfg.attention == "mla":
                 h = attn.mla_forward(lp["mixer"], cfg, h, positions,
-                                     window=window, unroll=unroll,
+                                     window=window,
                                      causal_skip=_flag(ctx, "causal_skip"))
             else:
                 h = attn.gqa_forward(lp["mixer"], cfg, h, positions,
-                                     window=window, unroll=unroll,
-                                     causal_skip=_flag(ctx, "causal_skip"),
-                                     use_pallas=_flag(ctx, "use_pallas"))
+                                     window=window,
+                                     causal_skip=_flag(ctx, "causal_skip"))
         elif spec.mixer == "cross_attn":
-            h = attn.cross_attention_forward(lp["mixer"], cfg, h, context,
-                                             unroll=unroll)
+            h = attn.cross_attention_forward(lp["mixer"], cfg, h, context)
         else:
             h = ssm.mamba_forward(lp["mixer"], cfg, h, ctx=ctx)
         x = x + h
@@ -196,9 +193,7 @@ def _run_groups(params, cfg: ModelConfig, x, positions, context, ctx,
                 lambda a: a[layer_offset:layer_offset + repeats * per]
                 .reshape(repeats, per, *a.shape[1:]), cross_stack)
             xs["cross"] = sl
-        (x, aux_total), _ = jax.lax.scan(
-            body, (x, aux_total), xs,
-            unroll=repeats if _flag(ctx, "unroll_layers") else 1)
+        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), xs)
         layer_offset += repeats * len(period)
     return x, aux_total
 
@@ -245,9 +240,7 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array, ctx=None
 
     if _flag(ctx, "remat"):
         body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(
-        body, frames, gp,
-        unroll=cfg.encoder_layers if _flag(ctx, "unroll_layers") else 1)
+    x, _ = jax.lax.scan(body, frames, gp)
     return rms_norm(x, enc["final_norm"]["scale"], cfg.norm_eps)
 
 
@@ -390,8 +383,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict,
                 lambda a: a[layer_offset:layer_offset + repeats * per]
                 .reshape(repeats, per, *a.shape[1:]), cross_all)
         x, nc = jax.lax.scan(
-            body, x, (gp, gc, cross_lp_stack, cross_c_stack),
-            unroll=repeats if _flag(ctx, "unroll_layers") else 1)
+            body, x, (gp, gc, cross_lp_stack, cross_c_stack))
         new_cache[f"group{gi}"] = nc
         layer_offset += repeats * len(period)
     if cfg.is_encoder_decoder:

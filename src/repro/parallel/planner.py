@@ -47,12 +47,8 @@ class ParallelCtx:
     decode_capacity_factor: float = 4.0
     remat: bool = True
     causal_skip: bool = False
-    unroll_layers: bool = False  # dry-run: unroll layer scans so XLA cost
-    # analysis (which visits while bodies once) counts every layer
     ep_weight_stationary: bool = False  # decode MoE: keep FSDP'd expert
     # weights sharded; psum tiny activations instead of gathering weights
-    use_pallas: bool = False  # attention via the Pallas kernel (compiled
-    # on TPU, interpreted on CPU; unsupported shapes raise)
     act_spec: Optional[P] = None
     logit_spec: Optional[P] = None
     notes: List[str] = field(default_factory=list)
@@ -75,7 +71,7 @@ class ParallelCtx:
 
 def make_ctx(mesh: Optional[Mesh], mesh_cfg: MeshConfig, *,
              remat: bool = True, causal_skip: bool = False,
-             use_ep: bool = True, unroll_layers: bool = False) -> ParallelCtx:
+             use_ep: bool = True) -> ParallelCtx:
     batch_axes = tuple(mesh_cfg.data_axes)
     b = batch_axes if len(batch_axes) > 1 else batch_axes[0]
     return ParallelCtx(
@@ -85,7 +81,6 @@ def make_ctx(mesh: Optional[Mesh], mesh_cfg: MeshConfig, *,
         remat=remat,
         causal_skip=causal_skip,
         use_ep=use_ep,
-        unroll_layers=unroll_layers,
         act_spec=P(b, None, None),
         logit_spec=P(b, None, mesh_cfg.model_axes[0]),
     )
@@ -360,7 +355,7 @@ def zero1_spec(param_spec: P, shape: Tuple[int, ...],
 def apply_fsdp(specs: Any, shapes: Any, mesh_cfg: MeshConfig) -> Any:
     """FSDP / ZeRO-3-style weight sharding: additionally shard each weight
     over the data axes on its first free divisible dim.  XLA all-gathers
-    layer weights on demand (visible in the dry-run's collective stats) —
+    layer weights on demand (all-gathers in the compiled HLO) —
     memory-forced for the >90B-param architectures at bf16."""
     return jax.tree.map(
         lambda sp, sh: zero1_spec(sp, sh.shape, mesh_cfg), specs, shapes,

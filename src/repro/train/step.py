@@ -67,12 +67,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                               params)
             init = (g0, jnp.zeros((), jnp.float32),
                     {"ce": jnp.zeros(()), "aux": jnp.zeros(())})
-            # dry-run cost mode unrolls so XLA cost analysis counts every
-            # microbatch (it visits while bodies once)
-            mb_unroll = nmb if (ctx is not None and
-                                getattr(ctx, "unroll_layers", False)) else 1
-            (grads, loss, metrics), _ = jax.lax.scan(body, init, mbs,
-                                                     unroll=mb_unroll)
+            (grads, loss, metrics), _ = jax.lax.scan(body, init, mbs)
             grads = jax.tree.map(lambda g: g / nmb, grads)
             loss = loss / nmb
             metrics = jax.tree.map(lambda m: m / nmb, metrics)

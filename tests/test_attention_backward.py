@@ -40,7 +40,7 @@ def _custom(q, k, v):
 def _autodiff(q, k, v):
     pos = jnp.arange(S)
     c = _Chunking(causal=True, window=None, q_chunk=Q_CHUNK,
-                  kv_chunk=KV_CHUNK, causal_skip=False, unroll=False)
+                  kv_chunk=KV_CHUNK, causal_skip=False)
     return _flash_forward(q, k, v, pos, pos, jnp.ones(S, bool), c)[0]
 
 

@@ -1,6 +1,7 @@
 """Attention-path properties: flash==plain (outputs and gradients),
-causal-skip==uniform scan, RoPE norm preservation & relative-position
-property, MLA absorption."""
+causal-skip==uniform scan (the chunked attention alone, the model forward and
+the train step), RoPE norm preservation & relative-position property, MLA
+absorption."""
 import dataclasses
 import functools
 
@@ -12,11 +13,16 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.configs import smoke_config
-from repro.models.attention import (_Chunking, _flash_attention_jnp,
-                                    _flash_forward, _group_q,
-                                    _plain_attention, mla_forward,
+from repro.core.types import TrainConfig
+from repro.models import forward, init_params
+from repro.models.attention import (_PLAIN_ATTN_MAX_SEQ, _Chunking,
+                                    _flash_attention_jnp, _flash_forward,
+                                    _group_q, _plain_attention, mla_forward,
                                     multihead_attention)
 from repro.models.modules import apply_rope
+from repro.optim.adamw import init_opt_state
+from repro.parallel.planner import ParallelCtx
+from repro.train.step import make_train_step
 
 
 def _qkv(key, b, sq, sk, h, kv, d, vd=None):
@@ -44,34 +50,19 @@ def test_flash_equals_plain(sq, sk, window):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_unrolled_flash_equals_scanned():
-    """The dry-run cost-mode unrolled flash (python chunk loops) must match
-    the scanned production form bit-for-bit-ish."""
-    key = jax.random.PRNGKey(7)
-    q, k, v = _qkv(key, 1, 4096, 4096, 2, 2, 32)
-    qg = _group_q(q, 2)
-    pos = jnp.arange(4096)
-    a = _flash_attention_jnp(qg, k, v, q_pos=pos, k_pos=pos, causal=True,
-                             window=None)
-    b = _flash_attention_jnp(qg, k, v, q_pos=pos, k_pos=pos, causal=True,
-                             window=None, unroll=True)
-    c = _flash_attention_jnp(qg, k, v, q_pos=pos, k_pos=pos, causal=True,
-                             window=None, unroll=True, causal_skip=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=2e-5)
-
-
-def test_causal_skip_equals_uniform():
+@pytest.mark.parametrize("seq,chunks", [
+    (512, {"q_chunk": 128, "kv_chunk": 128}),
+    (4096, {}),  # the default 1024-token chunks
+], ids=["s512_chunk128", "s4096_default_chunks"])
+def test_causal_skip_equals_uniform(seq, chunks):
     key = jax.random.PRNGKey(1)
-    q, k, v = _qkv(key, 1, 512, 512, 2, 2, 32)
+    q, k, v = _qkv(key, 1, seq, seq, 2, 2, 32)
     qg = _group_q(q, 2)
-    pos = jnp.arange(512)
+    pos = jnp.arange(seq)
     a = _flash_attention_jnp(qg, k, v, q_pos=pos, k_pos=pos, causal=True,
-                             window=None, q_chunk=128, kv_chunk=128,
-                             causal_skip=False)
+                             window=None, causal_skip=False, **chunks)
     b = _flash_attention_jnp(qg, k, v, q_pos=pos, k_pos=pos, causal=True,
-                             window=None, q_chunk=128, kv_chunk=128,
-                             causal_skip=True)
+                             window=None, causal_skip=True, **chunks)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
@@ -151,13 +142,13 @@ def _assert_grads_close(got, want):
     (256, 256, 4, 2, 32, 32, True, None, {}),
     (256, 256, 4, 2, 32, 32, True, 100, {}),
     (256, 256, 4, 2, 32, 32, True, 100, {"causal_skip": True}),
-    (2100, 2100, 2, 1, 16, 16, True, 700, {"unroll": True}),
+    (2100, 2100, 2, 1, 16, 16, True, 700, {"causal_skip": True}),
     (128, 300, 4, 4, 32, 32, False, None, {}),
     (200, 200, 4, 2, 32, 32, True, None, {}),
     (256, 256, 8, 2, 32, 32, True, None, {}),
     (256, 256, 4, 2, 48, 32, True, None, {}),
-], ids=["causal", "causal_window", "causal_skip", "unroll",
-        "noncausal_ragged_sk", "ragged_sq", "gqa_g4", "mla_vd_ne_hd"])
+], ids=["causal", "causal_window", "causal_skip",
+        "causal_skip_ragged_window", "noncausal_ragged_sk", "ragged_sq", "gqa_g4", "mla_vd_ne_hd"])
 def test_flash_gradients_equal_plain(sq, sk, h, kv, d, vd, causal, window,
                                      opts):
     """The chunked path's own backward gives the plain path's gradients."""
@@ -186,7 +177,7 @@ def test_flash_gradients_equal_autodiff_through_chunks():
     pos = jnp.arange(256)
     w = jax.random.normal(jax.random.fold_in(key, 3), qg.shape)
     c = _Chunking(causal=True, window=100, q_chunk=64, kv_chunk=64,
-                  causal_skip=False, unroll=False)
+                  causal_skip=False)
     autodiff = lambda q_, k_, v_: _flash_forward(
         q_, k_, v_, pos, pos, jnp.ones(256, bool), c)[0]
     flash = functools.partial(_flash_attention_jnp, q_pos=pos, k_pos=pos,
@@ -194,3 +185,46 @@ def test_flash_gradients_equal_autodiff_through_chunks():
                               kv_chunk=64)
     _assert_grads_close(jax.jit(lambda *a: _grads(flash, *a))(qg, k, v, w),
                         jax.jit(lambda *a: _grads(autodiff, *a))(qg, k, v, w))
+
+
+# Causal skipping from the model down: ``ParallelCtx.causal_skip`` reaches
+# the chunked attention through ``_apply_layer`` at a sequence above
+# ``_PLAIN_ATTN_MAX_SEQ`` (2304 tokens: 3 q chunks, the last one ragged).
+SKIP_SEQ = _PLAIN_ATTN_MAX_SEQ + 256
+
+
+def _skip_setup():
+    cfg = smoke_config("qwen2-0.5b")
+    key = jax.random.PRNGKey(8)
+    params = init_params(cfg, key)
+    tokens = jax.random.randint(key, (1, SKIP_SEQ), 0, cfg.vocab_size)
+    return cfg, params, tokens
+
+
+def test_model_forward_causal_skip_equals_default():
+    cfg, params, tokens = _skip_setup()
+    want, got = (jax.jit(lambda p, t, c=ctx: forward(cfg, p, t, ctx=c)[0])(
+        params, tokens) for ctx in (None, ParallelCtx(causal_skip=True)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_train_step_causal_skip_equals_default():
+    """Loss and gradients (the first moment AdamW keeps, over 1 - beta1)
+    of one step under causal skipping equal the default context's."""
+    cfg, params, tokens = _skip_setup()
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1)}
+    tcfg = TrainConfig()
+    opt = init_opt_state(params)
+    outs = [jax.jit(make_train_step(cfg, tcfg, ctx))(params, opt, batch)
+            for ctx in (ParallelCtx(remat=True),
+                        ParallelCtx(remat=True, causal_skip=True))]
+    (_, want_opt, want_m), (_, got_opt, got_m) = outs
+    assert float(got_m["loss"]) == pytest.approx(float(want_m["loss"]),
+                                                 rel=1e-6)
+    for a, b in zip(jax.tree.leaves(got_opt["m"]),
+                    jax.tree.leaves(want_opt["m"])):
+        scale = float(jnp.max(jnp.abs(b))) / (1 - tcfg.beta1)
+        np.testing.assert_allclose(np.asarray(a) / (1 - tcfg.beta1),
+                                   np.asarray(b) / (1 - tcfg.beta1),
+                                   rtol=1e-4, atol=1e-4 * scale)

@@ -12,7 +12,6 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from benchmarks.paper_claims import _placement_search_problem
-from benchmarks.roofline import ARCH_ORDER, SHAPE_ORDER, _rank, load
 
 from repro.ccl.cost import CostParams, algo_cost, cost_terms
 from repro.ccl.select import FlowSim
@@ -512,29 +511,3 @@ def test_export_cli_subprocess(tmp_path, dgx_plan):
         capture_output=True, text=True, env=env)
     assert proc.returncode == 0, proc.stderr
     assert (tmp_path / "rep.trace.json").exists()
-
-
-# ---------------------------------------------------------------------------
-# Satellite: roofline unknown-arch/shape guard
-# ---------------------------------------------------------------------------
-
-
-def test_roofline_rank_unknowns_sort_last():
-    assert _rank(ARCH_ORDER, ARCH_ORDER[0]) < _rank(ARCH_ORDER,
-                                                    ARCH_ORDER[-1])
-    assert _rank(ARCH_ORDER, ARCH_ORDER[-1]) < _rank(ARCH_ORDER,
-                                                     "brand-new-arch")
-    # unknowns order alphabetically among themselves
-    assert _rank(SHAPE_ORDER, "aaa_new") < _rank(SHAPE_ORDER, "zzz_new")
-
-
-def test_roofline_load_tolerates_unknown_entries(tmp_path):
-    rows = [{"arch": "qwen2-0.5b", "shape": "train_4k"},
-            {"arch": "never-heard-of-it", "shape": "train_4k"},
-            {"arch": "qwen2-0.5b", "shape": "weird_shape"}]
-    for i, r in enumerate(rows):
-        (tmp_path / f"r{i}_16x16.json").write_text(json.dumps(r))
-    loaded = load("16x16", results_dir=str(tmp_path))
-    assert [r["arch"] for r in loaded] == [
-        "qwen2-0.5b", "qwen2-0.5b", "never-heard-of-it"]
-    assert loaded[1]["shape"] == "weird_shape"
