@@ -15,16 +15,20 @@ recurrence is sequential.  Two implementations of the scan, one algorithm:
 
 ``ssd_chunked`` picks one from the shapes and, when the program is lowered,
 from the target platform (``lax.platform_dependent``); a program split over
-a mesh of several devices keeps the jnp scan (``mamba_forward``).
+a mesh of several devices keeps the jnp scan (``mamba_forward``).  The
+short causal convolution (and its SiLU) of each stream is chosen the same
+way by ``causal_conv``: the Pallas kernels of ``repro.kernels.causal_conv``
+or the jnp ``_causal_conv``.
 
 Projections are kept as separate tensors (z / x / B / C / dt and per-stream
 convs) instead of one fused ``in_proj`` so the tensor-parallel planner can
 shard the head-structured ones (z, x, dt, out) over the model axis while the
 state projections (B, C — shared across heads, GQA-like) stay replicated.
 
-In ``mamba_forward`` the scan runs under ``jax.named_scope("ssd")``, inside
-the layer's ``ssm`` block scope (``models.transformer``): a name in the HLO
-metadata only, by which the benchmark reads the scan's device time.
+In ``mamba_forward`` the scan runs under ``jax.named_scope("ssd")`` and each
+convolution under ``jax.named_scope("causal_conv")``, inside the layer's
+``ssm`` block scope (``models.transformer``): names in the HLO metadata
+only, by which the benchmark reads their device time.
 """
 from __future__ import annotations
 
@@ -81,13 +85,27 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> dict:
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal conv via shifted adds. x: (B, L, C); w: (K, C)."""
+    """Depthwise causal conv via shifted adds, then SiLU. x: (B, L, C);
+    w: (K, C); b: (C,).  XLA's fusions on any platform, and the Pallas
+    kernels' oracle."""
     k = w.shape[0]
     pad = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = jnp.zeros_like(x)
     for i in range(k):
         out = out + pad[:, i:i + x.shape[1], :] * w[i]
     return jax.nn.silu(out + b)
+
+
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """``_causal_conv``; where the Pallas kernels tile x
+    (``causal_conv.fits``), a program lowered for the TPU runs them instead
+    (forward and backward, ``kernels/causal_conv``).  The choice is made when
+    the program is lowered."""
+    from repro.kernels import causal_conv as kernels  # Pallas, where it runs
+    if not kernels.fits(x.shape, w.shape[0], x.dtype.itemsize):
+        return _causal_conv(x, w, b)
+    return jax.lax.platform_dependent(x, w, b, tpu=kernels.causal_conv,
+                                      default=_causal_conv)
 
 
 def _segsum(dac: jax.Array) -> jax.Array:
@@ -177,20 +195,28 @@ def ssd_chunked_jnp(x, dt, a, b, c, *, chunk: int = DEFAULT_CHUNK, h0=None):
 def mamba_forward(p: dict, cfg: ModelConfig, xin: jax.Array, *,
                   chunk: int = DEFAULT_CHUNK, ctx=None) -> jax.Array:
     """xin: (B, L, d) -> (B, L, d).  Under a ``ctx`` whose mesh holds more
-    than one device the scan stays in jnp: GSPMD cannot split the kernels,
-    and would gather their operands whole onto every device."""
+    than one device the convolutions and the scan stay in jnp: GSPMD cannot
+    split the kernels, and would gather their operands whole onto every
+    device."""
     din, n, h = cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_num_heads
     hd = cfg.ssm_head_dim
+    mesh = getattr(ctx, "mesh", None)
+    one = mesh is None or mesh.size == 1
+    conv_fn, scan = ((causal_conv, ssd_chunked) if one
+                     else (_causal_conv, ssd_chunked_jnp))
+
+    def conv(name, u):
+        with jax.named_scope("causal_conv"):
+            return conv_fn(u, p[name], p[name + "_bias"])
+
     z = xin @ p["z_proj"]
-    x = _causal_conv(xin @ p["x_proj"], p["conv_x"], p["conv_x_bias"])
-    b = _causal_conv(xin @ p["b_proj"], p["conv_b"], p["conv_b_bias"])
-    c = _causal_conv(xin @ p["c_proj"], p["conv_c"], p["conv_c_bias"])
+    x = conv("conv_x", xin @ p["x_proj"])
+    b = conv("conv_b", xin @ p["b_proj"])
+    c = conv("conv_c", xin @ p["c_proj"])
     dt = jax.nn.softplus(
         (xin @ p["dt_proj"]).astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
     xh = x.astype(jnp.float32).reshape(*x.shape[:2], h, hd)
-    mesh = getattr(ctx, "mesh", None)
-    scan = ssd_chunked if mesh is None or mesh.size == 1 else ssd_chunked_jnp
     with jax.named_scope("ssd"):
         y, _ = scan(xh, dt, a, b.astype(jnp.float32), c.astype(jnp.float32),
                     chunk=chunk)

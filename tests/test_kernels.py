@@ -6,6 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.causal_conv.ops import causal_conv
+from repro.kernels.causal_conv.ops import fits as conv_fits
+from repro.kernels.causal_conv.ref import causal_conv_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.moe_gmm.ops import moe_gmm
@@ -124,8 +127,10 @@ def test_ssd_scan_gradients(b, h, l, p, n, chunk, dtype):
     (48, 1, False),    # heads the kernels' lane groups do not tile
 ])
 def test_mamba_scan_dispatch(head_dim, devices, kernel):
-    """``mamba_forward`` stages the platform switch to the kernels only for
-    shapes they tile and a program on one device."""
+    """``mamba_forward`` stages the platform switch to the SSD kernels only
+    for shapes they tile and a program on one device; the convolution
+    kernels (d_inner 1,536 and the 128-wide B and C tile at any head size)
+    only on one device."""
     from types import SimpleNamespace
 
     from repro.configs import get_config
@@ -136,9 +141,51 @@ def test_mamba_scan_dispatch(head_dim, devices, kernel):
                             jax.random.PRNGKey(0))
     x = jax.ShapeDtypeStruct((1, 256, cfg.d_model), jnp.float32)
     ctx = SimpleNamespace(mesh=SimpleNamespace(size=devices))
-    jaxpr = jax.make_jaxpr(lambda p_, x_: mamba_forward(p_, cfg, x_, ctx=ctx))(
-        params, x)
-    assert ("platform_index" in str(jaxpr)) is kernel
+    jaxpr = str(jax.make_jaxpr(
+        lambda p_, x_: mamba_forward(p_, cfg, x_, ctx=ctx))(params, x))
+    assert ("ssd_forward" in jaxpr) is kernel
+    assert ("conv_forward" in jaxpr) is (devices == 1)
+    assert ("platform_index" in jaxpr) is (devices == 1)
+
+
+# (B, L, C): several slabs of rows and lane blocks; mamba2's B and C width
+# at its sequence; then shapes ``fits`` refuses (L not whole sublane tiles,
+# C not whole 128-lane blocks), which run the jnp conv
+CONV_SHAPES = [
+    ((2, 256, 128), jnp.float32, True),
+    ((2, 512, 384), jnp.float32, True),
+    ((1, 2048, 128), jnp.float32, True),
+    ((2, 272, 256), jnp.bfloat16, True),   # 16-row tiles, 16-row slabs
+    ((2, 100, 128), jnp.float32, False),
+    ((2, 256, 96), jnp.float32, False),
+]
+
+
+@pytest.mark.parametrize("shape,dtype,tiles", CONV_SHAPES)
+def test_causal_conv_against_oracle(shape, dtype, tiles):
+    """The conv kernels (forward, and dx, dw, db by ``jax.vjp``) against
+    the jnp ``_causal_conv``, where ``fits`` takes the shape; where it
+    does not, ``models.ssm.causal_conv`` stages the jnp conv alone."""
+    from repro.models.ssm import causal_conv as dispatch
+    ks = jax.random.split(jax.random.PRNGKey(shape[1] + shape[2]), 4)
+    x = jax.random.normal(ks[0], shape).astype(dtype)
+    w = (jax.random.normal(ks[1], (4, shape[2])) * 0.5).astype(dtype)
+    b = (jax.random.normal(ks[2], (shape[2],)) * 0.1).astype(dtype)
+    dy = jax.random.normal(ks[3], shape).astype(dtype)
+    assert conv_fits(shape, 4, x.dtype.itemsize) is tiles
+    staged = str(jax.make_jaxpr(dispatch)(x, w, b))
+    assert ("conv_forward" in staged) is tiles
+    if not tiles:
+        np.testing.assert_array_equal(dispatch(x, w, b),
+                                      causal_conv_ref(x, w, b))
+        return
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    out, pull = jax.vjp(causal_conv, x, w, b)
+    ref, pull_ref = jax.vjp(causal_conv_ref, x, w, b)
+    assert out.dtype == ref.dtype and _rel(out, ref) < tol
+    for name, got, want in zip(("x", "w", "b"), pull(dy), pull_ref(dy)):
+        assert got.dtype == want.dtype, name
+        assert _rel(got, want) < tol, (name, _rel(got, want))
 
 
 def test_ssd_scan_state_continuity():

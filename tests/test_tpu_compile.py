@@ -17,6 +17,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.ccl.primitives import IMPLEMENTATIONS, make_all_reduce
 from repro.configs import get_config
+from repro.kernels.causal_conv.ops import causal_conv
+from repro.kernels.causal_conv.ops import fits as conv_fits
 from repro.core.types import MeshConfig, TrainConfig
 from repro.kernels.compress.ops import dequantize, quantize
 from repro.kernels.flash_attention.ops import flash_attention
@@ -99,10 +101,25 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     assert len(_kernel_op_names(grad)) == 2   # forward, then backward
 
 
-def test_mamba2_layer_kernels_carry_ssd_scope(one_chip):
-    """A mamba2-130m layer's gradient runs the scan through the kernels,
-    whose op names keep the ``ssd`` scope that ``ssd_ms`` reads, forward
-    and backward."""
+@pytest.mark.parametrize("channels", [1536, 128])
+def test_causal_conv_compiles_at_mamba2_widths(one_chip, channels):
+    """The conv forward and backward kernels at the mamba2 cell's shapes:
+    B 16, L 2048, the x stream's 1,536 channels or B's and C's 128."""
+    f32 = jnp.float32
+    args = (_shape((16, 2048, channels), f32, one_chip),
+            _shape((4, channels), f32, one_chip),
+            _shape((channels,), f32, one_chip))
+    assert conv_fits(args[0].shape, 4)
+    _assert_kernel(_compile(causal_conv, *args))
+    grad = _compile(jax.grad(lambda *a: jnp.sum(causal_conv(*a) ** 2),
+                             argnums=range(3)), *args)
+    assert len(_kernel_op_names(grad)) == 2   # forward, then backward
+
+
+@pytest.fixture(scope="module")
+def mamba2_layer_kernels(one_chip):
+    """The op names of the Mosaic kernels of a mamba2-130m layer's
+    gradient."""
     cfg = get_config("mamba2-130m")
     p = jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip),
                      jax.eval_shape(lambda k: init_mamba(k, cfg, jnp.float32),
@@ -110,9 +127,53 @@ def test_mamba2_layer_kernels_carry_ssd_scope(one_chip):
     x = _shape((2, 1024, cfg.d_model), jnp.float32, one_chip)
     grad = _compile(jax.grad(lambda p_, x_: jnp.sum(
         mamba_forward(p_, cfg, x_) ** 2)), p, x)
-    names = _kernel_op_names(grad)
-    assert names and all(re.search(r"[/(]ssd[/)]", n) for n in names), names
-    assert {"transpose(" in n for n in names} == {True, False}, names
+    return _kernel_op_names(grad)
+
+
+def _in_scope(scope, op_name):
+    return re.search(rf"[/(]{scope}[/)]", op_name) is not None
+
+
+def test_mamba2_layer_kernels_carry_ssd_scope(mamba2_layer_kernels):
+    """A mamba2-130m layer's gradient runs the scan through the kernels,
+    whose op names keep the ``ssd`` scope that ``ssd_ms`` reads, forward
+    and backward; every kernel is the scan's or a conv's."""
+    names = mamba2_layer_kernels
+    ssd = [n for n in names if not _in_scope("causal_conv", n)]
+    assert ssd and all(_in_scope("ssd", n) for n in ssd), names
+    assert {"transpose(" in n for n in ssd} == {True, False}, names
+
+
+def test_mamba2_layer_kernels_carry_causal_conv_scope(mamba2_layer_kernels):
+    """The layer's three convolutions (x, B, C) run through the conv
+    kernels, forward and backward, whose op names keep the ``causal_conv``
+    scope that ``causal_conv_ms`` reads, outside the ``ssd`` scope."""
+    conv = [n for n in mamba2_layer_kernels if _in_scope("causal_conv", n)]
+    assert len(conv) == 6, mamba2_layer_kernels
+    assert not any(_in_scope("ssd", n) for n in conv), conv
+    assert sum("transpose(" in n for n in conv) == 3, conv
+
+
+def test_mamba2_train_step_fits_one_v5e(topo, one_chip):
+    """The ``mamba2-130m.train.s2048`` cell's step as the benchmark builds
+    it (16 x 2048 tokens, remat on, a one-device mesh, so that the SSD and
+    conv kernels run) fits one chip's HBM."""
+    cfg = get_config("mamba2-130m")
+    tcfg = TrainConfig()
+    mcfg = MeshConfig(shape=(1, 1))
+    mesh = make_mesh(mcfg.shape, mcfg.axis_names, devices=topo.devices[:1])
+    place = lambda tree: jax.tree.map(
+        lambda s: _shape(s.shape, s.dtype, one_chip), tree)
+    params = place(jax.eval_shape(lambda k: init_params(cfg, k),
+                                  jax.random.PRNGKey(0)))
+    opt = place(jax.eval_shape(init_opt_state, params))
+    batch = {k: _shape((16, 2048), jnp.int32, one_chip)
+             for k in ("tokens", "labels")}
+    step = make_train_step(cfg, tcfg, make_ctx(mesh, mcfg, remat=tcfg.remat))
+    compiled = _compile(step, params, opt, batch, donate_argnums=(0, 1))
+    assert "tpu_custom_call" in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes <= V5E_HBM_BYTES
 
 
 def test_moe_gmm_compiles_at_dbrx_widths(one_chip):
