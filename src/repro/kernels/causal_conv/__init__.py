@@ -1,1 +1,2 @@
-from repro.kernels.causal_conv.ops import causal_conv, fits  # noqa: F401
+from repro.kernels.causal_conv.ops import (  # noqa: F401
+    causal_conv, causal_conv_skip, fits)

@@ -18,9 +18,14 @@ after the current one.  The tap and bias gradients are summed over the
 program's rows into (8, lanes) float32 partials, which ``ops.py`` sums over
 batch rows and sublanes.
 
+Where the output also feeds a per-channel skip term ``y * d`` (Mamba-2's
+D skip), the backward takes that term's cotangent ds too: it uses
+``dy + ds * d`` in place of dy, and sums dd's partials ``ds * y`` as it does
+the bias's, so no sum of the two cotangents is written to HBM.
+
 So the forward reads x once and writes the output once; the backward reads
-x and dy once and writes dx once.  Every product and sum is float32,
-whatever the dtypes of x and the output.
+x and dy (and ds) once and writes dx once.  Every product and sum is
+float32, whatever the dtypes of x and the output.
 """
 from __future__ import annotations
 
@@ -54,9 +59,10 @@ def slab_rows(l: int, itemsize: int) -> int:
 
 
 def block_bytes(l: int, itemsize: int) -> int:
-    """VMEM of one backward program's double-buffered blocks (x, dy and dx,
-    the larger kernel) and its float32 slab temporaries."""
-    return 2 * 3 * l * LANES * itemsize + 24 * 128 * LANES * 4
+    """VMEM of one backward program's double-buffered blocks (x and dx, and
+    dy and ds, at most float32: the larger kernel) and its float32 slab
+    temporaries."""
+    return 2 * l * LANES * (2 * itemsize + 2 * 4) + 24 * 128 * LANES * 4
 
 
 def _window(x_ref, j, rows, halo):
@@ -104,22 +110,30 @@ def _fwd_kernel(x_ref, w_ref, b_ref, o_ref, *, rows, halo):
     lax.fori_loop(0, x_ref.shape[1] // rows, body, 0)
 
 
-def _bwd_kernel(x_ref, dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref, *,
-                rows, halo):
+def _bwd_kernel(*refs, rows, halo, skip):
+    if skip:
+        (x_ref, dy_ref, ds_ref, w_ref, b_ref, d_ref,
+         dx_ref, dw_ref, db_ref, dd_ref) = refs
+    else:
+        x_ref, dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref = refs
     k, lanes = w_ref.shape
     n = x_ref.shape[1] // rows
     w, bias = w_ref[...].astype(_F32), b_ref[...].astype(_F32)
     zeros = jnp.zeros((SUBLANES, lanes), _F32)
 
     def body(i, carry):
-        g_after, dws, db = carry
+        g_after, dws, db, dd = carry
         j = n - 1 - i
         r0 = pl.multiple_of(j * rows, rows)
         xs = _taps(_window(x_ref, j, rows, halo), k, halo)
         pre = _pre(xs, w, bias)
         sig = jax.nn.sigmoid(pre)
-        g = (dy_ref[0, pl.ds(r0, rows), :].astype(_F32)
-             * (sig * (1.0 + pre * (1.0 - sig))))
+        dy = dy_ref[0, pl.ds(r0, rows), :].astype(_F32)
+        if skip:
+            ds = ds_ref[0, pl.ds(r0, rows), :].astype(_F32)
+            dy = dy + ds * d_ref[...].astype(_F32)
+            dd = dd + _fold(ds * (pre * sig))
+        g = dy * (sig * (1.0 + pre * (1.0 - sig)))
         ext = jnp.concatenate([g, g_after], axis=0)     # g[t], t < r0+R+halo
         dx = g * w[k - 1:k]
         for s in range(1, k):                            # + w[K-1-s] g[t+s]
@@ -127,13 +141,16 @@ def _bwd_kernel(x_ref, dy_ref, w_ref, b_ref, dx_ref, dw_ref, db_ref, *,
                        * w[k - 1 - s:k - s])
         dx_ref[0, pl.ds(r0, rows), :] = dx.astype(dx_ref.dtype)
         dws = tuple(dws[s] + _fold(g * xs[s]) for s in range(k))
-        return g[:halo], dws, db + _fold(g)
+        return g[:halo], dws, db + _fold(g), dd
 
-    _, dws, db = lax.fori_loop(
-        0, n, body, (jnp.zeros((halo, lanes), _F32), (zeros,) * k, zeros))
+    _, dws, db, dd = lax.fori_loop(
+        0, n, body,
+        (jnp.zeros((halo, lanes), _F32), (zeros,) * k, zeros, zeros))
     for s in range(k):
         dw_ref[0, k - 1 - s] = dws[s]
     db_ref[0] = db
+    if skip:
+        dd_ref[0] = dd
 
 
 def _params(l, itemsize):
@@ -173,30 +190,37 @@ def conv_forward(x, w, b, *, out_dtype):
 
 
 @jax.jit
-def conv_backward(x, dy, w, b):
+def conv_backward(x, dy, w, b, ds=None, d=None):
     """The gradients of ``conv_forward`` at x for cotangent dy (B, L, C):
     dx (B, L, C) in x's dtype and the per-row partials of dw (B, K, 8, C)
-    and db (B, 8, C) in float32."""
+    and db (B, 8, C) in float32.  Given the cotangent ds (B, L, C) of the
+    skip term ``y * d`` (d (1, C)) as well, the output's cotangent is
+    ``dy + ds * d``, and the per-row partials of dd (B, 8, C) come fourth."""
     bsz, l, c = x.shape
     k = w.shape[0]
     isz = x.dtype.itemsize
-    seq, params = _specs(l, k)
+    skip = ds is not None
+    seq, (w_spec, row_spec) = _specs(l, k)
+    part = pl.BlockSpec((1, SUBLANES, LANES), lambda ib, ic: (ib, 0, ic))
+    part_shape = jax.ShapeDtypeStruct((bsz, SUBLANES, c), _F32)
+    args = (x, dy, ds, w, b, d) if skip else (x, dy, w, b)
     return pallas_call(
         functools.partial(_bwd_kernel, rows=slab_rows(l, isz),
-                          halo=halo_rows(isz)),
+                          halo=halo_rows(isz), skip=skip),
         grid=(bsz, c // LANES),
-        in_specs=[seq, seq] + params,
+        in_specs=([seq] * (2 + skip) + [w_spec, row_spec]
+                  + [row_spec] * skip),
         out_specs=[
             seq,
             pl.BlockSpec((1, k, SUBLANES, LANES),
                          lambda ib, ic: (ib, 0, 0, ic)),
-            pl.BlockSpec((1, SUBLANES, LANES), lambda ib, ic: (ib, 0, ic)),
-        ],
+        ] + [part] * (1 + skip),
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype),
-                   jax.ShapeDtypeStruct((bsz, k, SUBLANES, c), _F32),
-                   jax.ShapeDtypeStruct((bsz, SUBLANES, c), _F32)],
+                   jax.ShapeDtypeStruct((bsz, k, SUBLANES, c), _F32)]
+                  + [part_shape] * (1 + skip),
         compiler_params=_params(l, isz),
         cost_estimate=pl.CostEstimate(
-            flops=(6 * k + 10) * x.size, transcendentals=x.size,
-            bytes_accessed=x.size * (2 * isz + dy.dtype.itemsize)),
-    )(x, dy, w, b)
+            flops=(6 * k + 10 + 4 * skip) * x.size, transcendentals=x.size,
+            bytes_accessed=x.size * (2 * isz + sum(
+                t.dtype.itemsize for t in args[1:2 + skip]))),
+    )(*args)

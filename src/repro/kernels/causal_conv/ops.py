@@ -1,9 +1,11 @@
 """Mamba-2's causal depthwise convolution and SiLU through the Pallas
 kernels, differentiable, in the model's layout: ``causal_conv(x, w, b)``
-takes and returns what ``repro.models.ssm._causal_conv`` does.  A
-``jax.custom_vjp`` joins the forward kernel to the backward kernel; the
-residual is x alone, and the backward's per-row partials of the tap and
-bias gradients are summed here in float32.
+takes and returns what ``repro.models.ssm._causal_conv`` does, and
+``causal_conv_skip(x, w, b, d)`` what ``_causal_conv_skip`` does: the
+output in float32 and its D skip term ``y * d``.  A ``jax.custom_vjp`` joins
+the forward kernel to the backward kernel; the residual is the inputs
+alone, and the backward's per-row partials of the tap, bias (and d)
+gradients are summed here in float32.
 
 Which path runs where: ``models/ssm.py`` ``causal_conv`` calls this in a
 program lowered for the TPU at shapes that ``fits``; on any other
@@ -46,11 +48,30 @@ def _fwd(x, w, b):
     return out, (x, w, b)
 
 
-def _bwd(res, dy):
-    x, w, b = res
-    dx, dw, db = conv_backward(x, dy, w, b.reshape(1, -1))
-    return (dx, jnp.sum(dw, axis=(0, 2)).astype(w.dtype),
-            jnp.sum(db, axis=(0, 1)).astype(b.dtype))
+def _bwd(res, dy, ds=None):
+    x, w, b, *d = res
+    dx, dw, db, *dd = conv_backward(x, dy, w, b.reshape(1, -1), ds,
+                                    *(t.reshape(1, -1) for t in d))
+    sums = [jnp.sum(dw, axis=(0, 2)), jnp.sum(db, axis=(0, 1))] + [
+        jnp.sum(t, axis=(0, 1)) for t in dd]
+    return (dx,) + tuple(t.astype(p.dtype) for t, p in zip(sums, (w, b, *d)))
 
 
 causal_conv.defvjp(_fwd, _bwd)
+
+
+@jax.custom_vjp
+def causal_conv_skip(x, w, b, d):
+    """``(y, y * d)`` for ``y = causal_conv(x, w, b)`` in float32 and a
+    per-channel d (C,): the conv's output and a skip connection's term.
+    The backward kernel takes both outputs' cotangents and sums them on
+    chip.  The shapes must satisfy ``fits``."""
+    return _skip_fwd(x, w, b, d)[0]
+
+
+def _skip_fwd(x, w, b, d):
+    y = _fwd(x, w, b)[0].astype(jnp.float32)
+    return (y, y * d), (x, w, b, d)
+
+
+causal_conv_skip.defvjp(_skip_fwd, lambda res, cts: _bwd(res, *cts))

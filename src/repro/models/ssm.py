@@ -18,7 +18,9 @@ from the target platform (``lax.platform_dependent``); a program split over
 a mesh of several devices keeps the jnp scan (``mamba_forward``).  The
 short causal convolution (and its SiLU) of each stream is chosen the same
 way by ``causal_conv``: the Pallas kernels of ``repro.kernels.causal_conv``
-or the jnp ``_causal_conv``.
+or the jnp ``_causal_conv``.  The x stream's conv also yields the D skip
+term (``causal_conv_skip``), so that the kernels' backward sums the scan's
+and the skip's cotangents of x on chip.
 
 Projections are kept as separate tensors (z / x / B / C / dt and per-stream
 convs) instead of one fused ``in_proj`` so the tensor-parallel planner can
@@ -96,16 +98,37 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return jax.nn.silu(out + b)
 
 
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """``_causal_conv``; where the Pallas kernels tile x
-    (``causal_conv.fits``), a program lowered for the TPU runs them instead
-    (forward and backward, ``kernels/causal_conv``).  The choice is made when
-    the program is lowered."""
+def _causal_conv_skip(x: jax.Array, w: jax.Array, b: jax.Array,
+                      d: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``(y, y * d)``: ``_causal_conv``'s output y in float32 and the D skip
+    term of a per-channel d (C,)."""
+    y = _causal_conv(x, w, b).astype(jnp.float32)
+    return y, y * d
+
+
+def _conv_dispatch(kernel: str, oracle, x, w, *rest):
+    """``oracle(x, w, *rest)``; where the Pallas kernels tile x
+    (``causal_conv.fits``), a program lowered for the TPU runs
+    ``kernels.causal_conv``'s ``kernel`` instead (forward and backward).
+    The choice is made when the program is lowered."""
     from repro.kernels import causal_conv as kernels  # Pallas, where it runs
     if not kernels.fits(x.shape, w.shape[0], x.dtype.itemsize):
-        return _causal_conv(x, w, b)
-    return jax.lax.platform_dependent(x, w, b, tpu=kernels.causal_conv,
-                                      default=_causal_conv)
+        return oracle(x, w, *rest)
+    return jax.lax.platform_dependent(x, w, *rest,
+                                      tpu=getattr(kernels, kernel),
+                                      default=oracle)
+
+
+def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """``_causal_conv``, through the kernels where they run."""
+    return _conv_dispatch("causal_conv", _causal_conv, x, w, b)
+
+
+def causal_conv_skip(x: jax.Array, w: jax.Array, b: jax.Array,
+                     d: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``_causal_conv_skip``, through the kernels where they run: their
+    backward sums the two outputs' cotangents on chip."""
+    return _conv_dispatch("causal_conv_skip", _causal_conv_skip, x, w, b, d)
 
 
 def _segsum(dac: jax.Array) -> jax.Array:
@@ -202,25 +225,27 @@ def mamba_forward(p: dict, cfg: ModelConfig, xin: jax.Array, *,
     hd = cfg.ssm_head_dim
     mesh = getattr(ctx, "mesh", None)
     one = mesh is None or mesh.size == 1
-    conv_fn, scan = ((causal_conv, ssd_chunked) if one
-                     else (_causal_conv, ssd_chunked_jnp))
+    conv_fn, conv_skip_fn, scan = (
+        (causal_conv, causal_conv_skip, ssd_chunked) if one
+        else (_causal_conv, _causal_conv_skip, ssd_chunked_jnp))
 
-    def conv(name, u):
+    def conv(name, u, *d):
         with jax.named_scope("causal_conv"):
-            return conv_fn(u, p[name], p[name + "_bias"])
+            return (conv_skip_fn if d else conv_fn)(
+                u, p[name], p[name + "_bias"], *d)
 
     z = xin @ p["z_proj"]
-    x = conv("conv_x", xin @ p["x_proj"])
+    x, skip = conv("conv_x", xin @ p["x_proj"], jnp.repeat(p["D"], hd))
     b = conv("conv_b", xin @ p["b_proj"])
     c = conv("conv_c", xin @ p["c_proj"])
     dt = jax.nn.softplus(
         (xin @ p["dt_proj"]).astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
-    xh = x.astype(jnp.float32).reshape(*x.shape[:2], h, hd)
+    xh = x.reshape(*x.shape[:2], h, hd)
     with jax.named_scope("ssd"):
         y, _ = scan(xh, dt, a, b.astype(jnp.float32), c.astype(jnp.float32),
                     chunk=chunk)
-    y = y + xh * p["D"][:, None]
+    y = y + skip.reshape(xh.shape)
     y = y.reshape(*xin.shape[:2], din).astype(xin.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["norm"]["scale"], cfg.norm_eps)
     return y @ p["out_proj"]

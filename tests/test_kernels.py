@@ -6,9 +6,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.causal_conv.ops import causal_conv
+from repro.kernels.causal_conv.ops import causal_conv, causal_conv_skip
 from repro.kernels.causal_conv.ops import fits as conv_fits
-from repro.kernels.causal_conv.ref import causal_conv_ref
+from repro.kernels.causal_conv.ref import (causal_conv_ref,
+                                           causal_conv_skip_ref)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.moe_gmm.ops import moe_gmm
@@ -161,29 +162,40 @@ CONV_SHAPES = [
 ]
 
 
+@pytest.mark.parametrize("skip", [False, True])
 @pytest.mark.parametrize("shape,dtype,tiles", CONV_SHAPES)
-def test_causal_conv_against_oracle(shape, dtype, tiles):
+def test_causal_conv_against_oracle(shape, dtype, tiles, skip):
     """The conv kernels (forward, and dx, dw, db by ``jax.vjp``) against
     the jnp ``_causal_conv``, where ``fits`` takes the shape; where it
-    does not, ``models.ssm.causal_conv`` stages the jnp conv alone."""
-    from repro.models.ssm import causal_conv as dispatch
-    ks = jax.random.split(jax.random.PRNGKey(shape[1] + shape[2]), 4)
+    does not, ``models.ssm.causal_conv`` stages the jnp conv alone.  With
+    ``skip``, ``causal_conv_skip`` against ``_causal_conv_skip``: both
+    outputs, and dd too, from both outputs' cotangents."""
+    from repro.models import ssm
+    ks = jax.random.split(jax.random.PRNGKey(shape[1] + shape[2]), 6)
     x = jax.random.normal(ks[0], shape).astype(dtype)
     w = (jax.random.normal(ks[1], (4, shape[2])) * 0.5).astype(dtype)
     b = (jax.random.normal(ks[2], (shape[2],)) * 0.1).astype(dtype)
     dy = jax.random.normal(ks[3], shape).astype(dtype)
+    args = (x, w, b)
+    kernel, ref_fn, dispatch = causal_conv, causal_conv_ref, ssm.causal_conv
+    if skip:
+        args += (jax.random.normal(ks[4], (shape[2],)),)
+        dy = (dy.astype(jnp.float32), jax.random.normal(ks[5], shape))
+        kernel, ref_fn = causal_conv_skip, causal_conv_skip_ref
+        dispatch = ssm.causal_conv_skip
     assert conv_fits(shape, 4, x.dtype.itemsize) is tiles
-    staged = str(jax.make_jaxpr(dispatch)(x, w, b))
+    staged = str(jax.make_jaxpr(dispatch)(*args))
     assert ("conv_forward" in staged) is tiles
     if not tiles:
-        np.testing.assert_array_equal(dispatch(x, w, b),
-                                      causal_conv_ref(x, w, b))
+        jax.tree.map(np.testing.assert_array_equal, dispatch(*args),
+                     ref_fn(*args))
         return
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    out, pull = jax.vjp(causal_conv, x, w, b)
-    ref, pull_ref = jax.vjp(causal_conv_ref, x, w, b)
-    assert out.dtype == ref.dtype and _rel(out, ref) < tol
-    for name, got, want in zip(("x", "w", "b"), pull(dy), pull_ref(dy)):
+    out, pull = jax.vjp(kernel, *args)
+    ref, pull_ref = jax.vjp(ref_fn, *args)
+    for got, want in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        assert got.dtype == want.dtype and _rel(got, want) < tol
+    for name, got, want in zip("xwbd", pull(dy), pull_ref(dy)):
         assert got.dtype == want.dtype, name
         assert _rel(got, want) < tol, (name, _rel(got, want))
 

@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.ccl.primitives import IMPLEMENTATIONS, make_all_reduce
 from repro.configs import get_config
-from repro.kernels.causal_conv.ops import causal_conv
+from repro.kernels.causal_conv.ops import causal_conv, causal_conv_skip
 from repro.kernels.causal_conv.ops import fits as conv_fits
 from repro.core.types import MeshConfig, TrainConfig
 from repro.kernels.compress.ops import dequantize, quantize
@@ -116,18 +116,39 @@ def test_causal_conv_compiles_at_mamba2_widths(one_chip, channels):
     assert len(_kernel_op_names(grad)) == 2   # forward, then backward
 
 
+def test_causal_conv_skip_compiles_at_mamba2_width(one_chip):
+    """The x stream's conv with its D skip term at the mamba2 cell's shape:
+    the backward is one kernel that takes both outputs' cotangents."""
+    f32 = jnp.float32
+    args = (_shape((16, 2048, 1536), f32, one_chip),
+            _shape((4, 1536), f32, one_chip), _shape((1536,), f32, one_chip),
+            _shape((1536,), f32, one_chip))
+
+    def loss(*a):
+        y, skip = causal_conv_skip(*a)
+        return jnp.sum(y * y) + jnp.sum(jnp.sin(skip))
+
+    grad = _compile(jax.grad(loss, argnums=range(4)), *args)
+    assert len(_kernel_op_names(grad)) == 2   # forward, then backward
+
+
 @pytest.fixture(scope="module")
-def mamba2_layer_kernels(one_chip):
-    """The op names of the Mosaic kernels of a mamba2-130m layer's
-    gradient."""
+def mamba2_layer_grad(one_chip):
+    """A mamba2-130m layer's gradient, compiled."""
     cfg = get_config("mamba2-130m")
     p = jax.tree.map(lambda s: _shape(s.shape, s.dtype, one_chip),
                      jax.eval_shape(lambda k: init_mamba(k, cfg, jnp.float32),
                                     jax.random.PRNGKey(0)))
     x = _shape((2, 1024, cfg.d_model), jnp.float32, one_chip)
-    grad = _compile(jax.grad(lambda p_, x_: jnp.sum(
+    return _compile(jax.grad(lambda p_, x_: jnp.sum(
         mamba_forward(p_, cfg, x_) ** 2)), p, x)
-    return _kernel_op_names(grad)
+
+
+@pytest.fixture(scope="module")
+def mamba2_layer_kernels(mamba2_layer_grad):
+    """The op names of the Mosaic kernels of a mamba2-130m layer's
+    gradient."""
+    return _kernel_op_names(mamba2_layer_grad)
 
 
 def _in_scope(scope, op_name):
@@ -152,6 +173,19 @@ def test_mamba2_layer_kernels_carry_causal_conv_scope(mamba2_layer_kernels):
     assert len(conv) == 6, mamba2_layer_kernels
     assert not any(_in_scope("ssd", n) for n in conv), conv
     assert sum("transpose(" in n for n in conv) == 3, conv
+
+
+def test_mamba2_layer_sums_x_cotangents_in_the_conv_kernel(
+        mamba2_layer_grad):
+    """x's cotangents from the scan and from the D skip meet inside the
+    x stream's conv backward kernel, which reads both (with the
+    pre-activation, taps, bias and D); B's and C's read one."""
+    arity = sorted(
+        len(re.search(r"custom-call\(([^)]*)\)", line).group(1).split(","))
+        for line in mamba2_layer_grad.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and "conv_backward" in line)
+    assert arity == [4, 4, 6], arity
 
 
 def test_mamba2_train_step_fits_one_v5e(topo, one_chip):
